@@ -40,13 +40,13 @@ from repro.kernels import ref as kref
 
 try:
     from .common import ragged_paged_batch
+    from .roofline import peaks_for
 except ImportError:                      # run as a plain script
     from common import ragged_paged_batch
+    from roofline import peaks_for
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
 
-HBM_BW = 819e9
-PEAK = 197e12
 
 
 def _time(fn, *args, iters=5):
@@ -60,6 +60,7 @@ def _time(fn, *args, iters=5):
 
 
 def hadamard_rows():
+    peak = peaks_for(jax.devices()[0].device_kind)
     m, d, b = 2048, 8192, 32
     x = jax.random.normal(jax.random.PRNGKey(0), (m, d), jnp.float32)
 
@@ -75,15 +76,15 @@ def hadamard_rows():
     rows = []
     print("# kernel model (v5e bf16) + CPU jnp reference timing")
     print("op,cpu_ref_us,model_bytes,model_flops,v5e_time_us,bound")
-    t_mem = m * d * 2 * 2 / HBM_BW * 1e6
-    t_cmp = flops_rot / PEAK * 1e6
+    t_mem = m * d * 2 * 2 / peak["hbm_bw"] * 1e6
+    t_cmp = flops_rot / peak["flops"] * 1e6
     rows.append({"op": f"block_hadamard_b{b}", "cpu_ref_us": round(us_rot),
                  "model_bytes": m * d * 4, "model_flops": flops_rot,
                  "v5e_time_us": round(max(t_mem, t_cmp), 1),
                  "bound": "memory" if t_mem > t_cmp else "compute"})
     print(f"block_hadamard_b{b},{us_rot:.0f},{m*d*4},{flops_rot},"
           f"{max(t_mem,t_cmp):.1f},{'memory' if t_mem>t_cmp else 'compute'}")
-    t_mem_f = bytes_fused / HBM_BW * 1e6
+    t_mem_f = bytes_fused / peak["hbm_bw"] * 1e6
     rows.append({"op": f"hadamard_quant_fused_b{b}",
                  "cpu_ref_us": round(us_fused), "model_bytes": bytes_fused,
                  "model_flops": flops_rot,

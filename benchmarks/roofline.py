@@ -26,9 +26,22 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12        # bf16 per chip (v5e)
-HBM_BW = 819e9             # B/s per chip
-LINK_BW = 50e9             # B/s per ICI link
+# Published peaks of one chip, keyed by the `device_kind` JAX reports
+# (Google Cloud documentation, "TPU v5e"): bf16 FLOP/s, HBM bytes/s, and
+# bytes/s per ICI link (1,600 Gbit/s over four links). A device that is
+# not here is an error, not a default.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks_for(device_kind: str | None) -> dict:
+    """The peak table row of `device_kind`; raises for any other device."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak figures for device_kind {device_kind!r} "
+                         f"(known: {sorted(PEAKS)})") from None
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
@@ -95,9 +108,10 @@ def roofline_row(rec: dict) -> dict | None:
     chips = 1
     for s in rec["mesh"]["shape"]:
         chips *= s
-    t_compute = hc["flops_per_device"] / PEAK_FLOPS
-    t_memory = hc["bytes_per_device"] / HBM_BW
-    t_coll = sum(hc["collective_bytes_by_kind"].values()) / LINK_BW
+    peak = peaks_for(rec.get("device_kind"))
+    t_compute = hc["flops_per_device"] / peak["flops"]
+    t_memory = hc["bytes_per_device"] / peak["hbm_bw"]
+    t_coll = sum(hc["collective_bytes_by_kind"].values()) / peak["link_bw"]
     dominant = max(
         (("compute", t_compute), ("memory", t_memory),
          ("collective", t_coll)), key=lambda kv: kv[1])[0]
@@ -106,7 +120,7 @@ def roofline_row(rec: dict) -> dict | None:
     useful = mf_dev / hc["flops_per_device"] if hc["flops_per_device"] else 0
     # roofline fraction: useful work at peak / modeled step time
     t_step = max(t_compute, t_memory, t_coll)
-    frac = (mf_dev / PEAK_FLOPS) / t_step if t_step > 0 else 0.0
+    frac = (mf_dev / peak["flops"]) / t_step if t_step > 0 else 0.0
     mem = rec.get("memory", {})
     hbm = (mem.get("argument_size_bytes", 0)
            + mem.get("temp_size_bytes", 0)) / 2 ** 30

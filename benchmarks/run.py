@@ -1,7 +1,8 @@
 """Benchmark runner: one harness per paper table/figure + kernel timings.
 
 Prints ``name,us_per_call,derived`` CSV rows per harness, then each
-harness's own table output.
+harness's own table output. A harness that raises gets a ``fail:`` row,
+the others still run, and the runner exits 1.
 """
 from __future__ import annotations
 
@@ -23,10 +24,10 @@ def _run(name, fn, *args):
         buf.write(f"\nERROR {e}\n")
     dt_us = (time.perf_counter() - t0) * 1e6
     print(f"{name},{dt_us:.0f},{status}")
-    return name, buf.getvalue()
+    return name, buf.getvalue(), status == "ok"
 
 
-def main() -> None:
+def main() -> int:
     from . import (fig3_delta, fig45_bounds, massdiff_speed,
                    table1_blocksize, table2_formats, table34_opcounts,
                    table6_permutations)
@@ -50,10 +51,11 @@ def main() -> None:
         name, fn, *rest = job
         outputs.append(_run(name, fn, *rest))
     print()
-    for name, text in outputs:
+    for name, text, _ in outputs:
         print(f"===== {name} =====")
         print(text)
+    return 0 if all(ok for _, _, ok in outputs) else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -32,8 +32,11 @@ def _kernel(x_ref, h_ref, codes_ref, scale_ref, zero_ref, *, bits, n_slabs):
     td = h.shape[0]
     # Rotate slab-by-slab (static unroll keeps everything MXU matmuls).
     xs = x.reshape(tm, n_slabs, td)
+    # f32 at full precision (the chip's default may take one bf16 pass),
+    # the same arithmetic as the reference's `ops._rotate_mm`
     y = jax.lax.dot_general(
         xs, h, dimension_numbers=(((2,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )                                            # [TM, n_slabs, TD]
     y = y.reshape(tm, d)

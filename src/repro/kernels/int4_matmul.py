@@ -26,6 +26,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["int4_matmul"]
 
+_INT8_ROWS = 32   # sublane tile of an int8 block on the TPU
+
 
 def _divisor_tile(dim: int, pref: int, *, multiple: int = 1) -> int:
     """Largest divisor of `dim` that is ≤ `pref` and a multiple of
@@ -61,26 +63,29 @@ def _tile_or_pad(dim: int, pref: int, *, multiple: int = 1) -> tuple[int, int]:
     return t, dim + (-dim) % t
 
 
-def _kernel(qa_ref, wp_ref, sa_ref, za_ref, sw_ref, colsum_ref, o_ref,
-            acc_ref, *, n_k):
+def _kernel(qe_ref, qo_ref, wp_ref, sa_ref, za_ref, sw_ref, colsum_ref,
+            o_ref, acc_ref, *, n_k):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qa = qa_ref[...].astype(jnp.int32)            # [TM, TK]
-    wp = wp_ref[...]                               # [TK/2, TN] packed uint8
-    lo = (wp & 0xF).astype(jnp.int32)
-    hi = ((wp >> 4) & 0xF).astype(jnp.int32)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    tk2, tn = wp.shape
-    w = jnp.stack([lo, hi], axis=1).reshape(2 * tk2, tn)   # [TK, TN] int32
-    acc_ref[...] += jax.lax.dot_general(
-        qa, w, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    # Packed byte j of a column holds weight rows 2j (low nibble) and 2j+1
+    # (high nibble). Rather than interleave them back into [TK, TN], the
+    # product splits over the two nibble planes against the even/odd
+    # activation columns: q_a @ q_w = q_a[:, 0::2] @ lo + q_a[:, 1::2] @ hi.
+    # The nibbles are sign-extended with int32 shifts (Mosaic legalizes no
+    # 8-bit shift) and narrowed back to int8 for the MXU's int8 path.
+    w = wp_ref[...].astype(jnp.int32)             # [TK/2, TN]
+    lo = ((w << 28) >> 28).astype(jnp.int8)
+    hi = ((w << 24) >> 28).astype(jnp.int8)
+    dims = (((1,), (0,)), ((), ()))
+    acc_ref[...] += (
+        jax.lax.dot_general(qe_ref[...], lo, dims,
+                            preferred_element_type=jnp.int32)
+        + jax.lax.dot_general(qo_ref[...], hi, dims,
+                              preferred_element_type=jnp.int32))
 
     @pl.when(k_idx == n_k - 1)
     def _epilogue():
@@ -107,7 +112,8 @@ def int4_matmul(act_codes: jnp.ndarray, act_scale: jnp.ndarray,
         raise ValueError(f"packed K mismatch: acts K={k}, weights K={2 * k2}")
     w_scale = w_scale.reshape(1, n).astype(jnp.float32)
 
-    tm = min(tm, max(8, m))
+    # int8 blocks tile natively at 32 rows; decode's few rows pad up to it
+    tm = min(tm, -(-m // _INT8_ROWS) * _INT8_ROWS)
     tn, np_ = _tile_or_pad(n, tn)
     tk, kp = _tile_or_pad(k, tk, multiple=2)
     if np_ > n:
@@ -139,13 +145,17 @@ def int4_matmul(act_codes: jnp.ndarray, act_scale: jnp.ndarray,
         act_zero = jnp.pad(act_zero, ((0, pad_m), (0, 0)))
     mp = act_codes.shape[0]
     n_k = kp // tk
+    # even/odd activation columns meet the low/high nibble planes
+    act_even, act_odd = act_codes[:, 0::2], act_codes[:, 1::2]
+    w_packed = jax.lax.bitcast_convert_type(w_packed, jnp.int8)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_k=n_k),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         grid=(mp // tm, np_ // tn, n_k),
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((tm, tk // 2), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((tm, tk // 2), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((tk // 2, tn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((tm, 1), lambda i, j, kk: (i, 0)),
             pl.BlockSpec((tm, 1), lambda i, j, kk: (i, 0)),
@@ -155,7 +165,7 @@ def int4_matmul(act_codes: jnp.ndarray, act_scale: jnp.ndarray,
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.int32)],
         interpret=interpret,
-    )(act_codes, w_packed, act_scale, act_zero, w_scale, colsum)
+    )(act_even, act_odd, w_packed, act_scale, act_zero, w_scale, colsum)
 
     if pad_m or np_ > n:
         out = out[:m, :n]

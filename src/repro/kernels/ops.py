@@ -111,6 +111,7 @@ def _rotate_mm(x: jnp.ndarray, b: int) -> jnp.ndarray:
     xs = x.astype(jnp.float32).reshape(-1, d // td, td)
     y = jax.lax.dot_general(xs, h,
                             dimension_numbers=(((2,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     return y.reshape(*lead, d)
 

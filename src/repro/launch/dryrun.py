@@ -339,6 +339,9 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
             "multi_pod": multi_pod,
             "mesh": {"shape": list(mesh.devices.shape),
                      "axes": list(mesh.axis_names)},
+            # the device the program was compiled for; roofline.py finds
+            # its peaks by this kind and refuses any it does not know
+            "device_kind": mesh.devices.flat[0].device_kind,
             "num_microbatches": plan.num_microbatches,
             "remat_policy": remat_policy,
             "moment_dtype": moment_dtype,

@@ -15,7 +15,10 @@ MULTI_POD = (2, 16, 16)          # 2 pods = 512 chips
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # GSPMD (Auto) axes: the train and dry-run steps place data through
+    # sharding constraints, which explicit axes do not take
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def device_count_required(multi_pod: bool) -> int:

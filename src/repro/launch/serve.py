@@ -33,6 +33,10 @@ dispatches as Chrome Trace JSON (open in https://ui.perfetto.dev), and
 integer path. Every engine run ends with a one-line summary (tokens/s,
 per-token latency quantiles, peak pool utilization, admission wait)
 computed from the same registry snapshot.
+
+`main` is a sequence of steps — `build_model_params`, `quantize`,
+`build_engine`, `run_engine` — that `chip_smoke.py` calls in the same
+order to serve the full-width model on the chip.
 """
 import argparse
 import json
@@ -44,6 +48,7 @@ import numpy as np
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core import pipeline as PL
 from repro.core.synthetic import inject_outlier_channels
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import build_model
 from repro.serve.engine import (EngineRequest, SamplingParams, ServeEngine,
                                 as_servable, pages_for)
@@ -91,7 +96,7 @@ def summary_line(snap: dict) -> str:
     return out + f" | admission wait p95 {_ms(wait['p95'])}"
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", "--model", dest="arch",
                     default="qwen1.5-0.5b", choices=ARCH_IDS)
@@ -99,6 +104,10 @@ def main(argv=None):
     ap.add_argument("--preset", default="perq_star",
                     choices=sorted(PL.PRESETS))
     ap.add_argument("--block-size", type=int, default=32)
+    ap.add_argument("--rounding", default=None,
+                    choices=["rtn", "gptq", "qronos"],
+                    help="override the preset's weight rounding (RTN skips "
+                    "the Hessian solves, which dominate PTQ at full width)")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
     ap.add_argument("--requests", type=int, default=6)
@@ -157,63 +166,48 @@ def main(argv=None):
                     "never-admitted requests cancel, in-flight work "
                     "(including swapped residents) finishes, and the "
                     "engine asserts every tier came back empty")
-    args = ap.parse_args(argv)
+    return ap
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+
+def build_model_params(cfg):
+    """The served model, built from seed 0 with LLM-like outlier channels
+    injected (no checkpoint is needed)."""
     model = build_model(cfg)
-    params = inject_outlier_channels(model.init(jax.random.PRNGKey(0)))
+    return model, inject_outlier_channels(model.init(jax.random.PRNGKey(0)))
 
+
+def quantize(args, model, params):
+    """PTQ per `args` (calibrated on random tokens from seed 1) →
+    (served model, served params); the bf16 model under --no-quant."""
     if args.no_quant:
-        smodel, sparams = model, params
-    else:
-        key = jax.random.PRNGKey(1)
-        calib = [{"tokens": jax.random.randint(key, (4, 128), 0, cfg.vocab),
-                  "labels": jnp.zeros((4, 128), jnp.int32)}]
-        res = PL.quantize_model(
-            model, params, calib,
-            PL.preset(args.preset, block_size=args.block_size,
-                      cayley_steps=8))
-        smodel, sparams = PL.build_quantized_model(model, res), res.params
-        print(f"quantized with {args.preset} (b={args.block_size})")
+        return model, params
+    cfg = model.cfg
+    key = jax.random.PRNGKey(1)
+    calib = [{"tokens": jax.random.randint(key, (4, 128), 0, cfg.vocab),
+              "labels": jnp.zeros((4, 128), jnp.int32)}]
+    overrides = {"rounding": args.rounding} if args.rounding else {}
+    res = PL.quantize_model(
+        model, params, calib,
+        PL.preset(args.preset, block_size=args.block_size, cayley_steps=8,
+                  **overrides))
+    print(f"quantized with {args.preset} (b={args.block_size}"
+          + (f", {args.rounding}" if args.rounding else "") + ")")
+    return PL.build_quantized_model(model, res), res.params
 
+
+def default_prompts(args, cfg) -> list[list[int]]:
+    """`--requests` random prompts of 3–8 tokens (seed 0), each behind
+    the same `--shared-prefix`-token system prompt."""
     rng = np.random.default_rng(0)
     system = rng.integers(0, cfg.vocab, size=args.shared_prefix).tolist()
-    prompts = [system + rng.integers(0, cfg.vocab,
-                                     size=int(rng.integers(3, 9))).tolist()
-               for _ in range(args.requests)]
+    return [system + rng.integers(0, cfg.vocab,
+                                  size=int(rng.integers(3, 9))).tolist()
+            for _ in range(args.requests)]
 
-    if args.probe_every and not args.integer_path:
-        raise SystemExit("--probe-every needs --integer-path: the probes "
-                         "read the fused rotate+quantize site")
-    if args.legacy_scheduler:
-        if args.integer_path:
-            raise SystemExit("--legacy-scheduler cannot drive the integer "
-                             "path; the paged engine serves it")
-        if args.metrics_json or args.trace:
-            raise SystemExit("--metrics-json/--trace instrument the paged "
-                             "engine; drop --legacy-scheduler")
-        if args.top_k > 0 or args.top_p < 1.0:
-            raise SystemExit("--legacy-scheduler has no top-k/top-p "
-                             "support; drop the flags or use the engine")
-        if args.prefix_cache:
-            raise SystemExit("--prefix-cache is a paged-engine feature; "
-                             "drop --legacy-scheduler")
-        if args.swap_host_mb is not None or args.drain_after is not None:
-            raise SystemExit("--swap-host-mb/--drain-after are paged-"
-                             "engine features; drop --legacy-scheduler")
-        sched = BatchScheduler(smodel, sparams, slots=args.slots,
-                               max_len=args.max_len,
-                               temperature=args.temperature)
-        for rid, prompt in enumerate(prompts):
-            sched.submit(Request(rid=rid, prompt=prompt,
-                                 max_new=args.max_new))
-        done = sched.run()
-        for r in sorted(done, key=lambda r: r.rid):
-            print(f"req {r.rid}: {r.prompt} → {r.generated}")
-        return
 
+def build_engine(args, cfg, smodel, sparams, prompts):
+    """The paged engine serving `smodel` per `args`, with a pool sized
+    for `prompts` → (engine, label)."""
     if args.integer_path:
         if cfg.family not in ("dense", "vlm"):
             raise SystemExit(f"--integer-path packs dense projections only; "
@@ -250,20 +244,70 @@ def main(argv=None):
                          swap_host_mb=args.swap_host_mb,
                          swap_policy=args.swap_policy,
                          tracer=tracer, quality_probes=probes)
+    return engine, label
+
+
+def run_engine(args, engine, prompts):
+    """Submit `prompts` and serve them to completion (or drain after
+    `--drain-after` steps) → the requests that reached a terminal state."""
     for rid, prompt in enumerate(prompts):
         engine.submit(EngineRequest(
             rid=rid, prompt=prompt,
             sampling=SamplingParams(temperature=args.temperature,
                                     max_new=args.max_new,
                                     top_k=args.top_k, top_p=args.top_p)))
-    if args.drain_after is not None:
-        done = []
-        while (engine.queue or engine.active) \
-                and engine.n_steps < args.drain_after:
-            done.extend(engine.step())
-        done.extend(engine.drain())
-    else:
-        done = engine.run()
+    if args.drain_after is None:
+        return engine.run()
+    done = []
+    while (engine.queue or engine.active) \
+            and engine.n_steps < args.drain_after:
+        done.extend(engine.step())
+    done.extend(engine.drain())
+    return done
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    smodel, sparams = quantize(args, *build_model_params(cfg))
+    prompts = default_prompts(args, cfg)
+
+    if args.probe_every and not args.integer_path:
+        raise SystemExit("--probe-every needs --integer-path: the probes "
+                         "read the fused rotate+quantize site")
+    if args.legacy_scheduler:
+        if args.integer_path:
+            raise SystemExit("--legacy-scheduler cannot drive the integer "
+                             "path; the paged engine serves it")
+        if args.metrics_json or args.trace:
+            raise SystemExit("--metrics-json/--trace instrument the paged "
+                             "engine; drop --legacy-scheduler")
+        if args.top_k > 0 or args.top_p < 1.0:
+            raise SystemExit("--legacy-scheduler has no top-k/top-p "
+                             "support; drop the flags or use the engine")
+        if args.prefix_cache:
+            raise SystemExit("--prefix-cache is a paged-engine feature; "
+                             "drop --legacy-scheduler")
+        if args.swap_host_mb is not None or args.drain_after is not None:
+            raise SystemExit("--swap-host-mb/--drain-after are paged-"
+                             "engine features; drop --legacy-scheduler")
+        sched = BatchScheduler(smodel, sparams, slots=args.slots,
+                               max_len=args.max_len,
+                               temperature=args.temperature)
+        for rid, prompt in enumerate(prompts):
+            sched.submit(Request(rid=rid, prompt=prompt,
+                                 max_new=args.max_new))
+        done = sched.run()
+        for r in sorted(done, key=lambda r: r.rid):
+            print(f"req {r.rid}: {r.prompt} → {r.generated}")
+        return
+
+    engine, label = build_engine(args, cfg, smodel, sparams, prompts)
+    done = run_engine(args, engine, prompts)
     print(f"{label}: served {len(done)} requests over {args.slots} slots "
           f"in {engine.n_steps} engine steps "
           f"({engine.n_prefill_tokens} prefill + "
@@ -275,7 +319,7 @@ def main(argv=None):
     snap = engine.metrics_snapshot()
     validate_snapshot(snap)     # never write an off-schema artifact
     print(summary_line(snap))
-    if probes is not None:
+    if engine.quality_probes is not None:
         imb = snap["histograms"]["quality.l1_imbalance_post"]
         print(f"quality: post-rotation l1 imbalance p50 {imb['p50']:.3f} "
               f"over {imb['count']} layer observations "
@@ -285,10 +329,10 @@ def main(argv=None):
         with open(args.metrics_json, "w") as f:
             json.dump(snap, f, indent=1)
         print(f"metrics snapshot → {args.metrics_json}")
-    if tracer is not None:
-        validate_trace(tracer.to_dict())
-        tracer.save(args.trace)
-        print(f"trace ({len(tracer.events)} events) → {args.trace}")
+    if engine.tracer is not None:
+        validate_trace(engine.tracer.to_dict())
+        engine.tracer.save(args.trace)
+        print(f"trace ({len(engine.tracer.events)} events) → {args.trace}")
 
 
 if __name__ == "__main__":

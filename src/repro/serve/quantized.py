@@ -68,6 +68,9 @@ def pack_dense_params(params: Params, cfg: ArchConfig) -> Params:
     grid the PTQ pipeline produced.
     """
     L_ = params["layers"]
+    # jit'd so the scale search's per-candidate round trips fuse instead
+    # of materializing [7, n_layers, K, N] f32 intermediates on device
+    pack = jax.jit(jax.vmap(kops.pack_int4_weights))
     out = {
         "embed": params["embed"],
         "final_norm": params["final_norm"],
@@ -79,7 +82,7 @@ def pack_dense_params(params: Params, cfg: ArchConfig) -> Params:
     }
     packed_attn = {}
     for name in ("wq", "wk", "wv", "wo"):
-        packed_attn[name] = jax.vmap(kops.pack_int4_weights)(L_["attn"][name])
+        packed_attn[name] = pack(L_["attn"][name])
     for bias in ("bq", "bk", "bv"):
         if bias in L_["attn"]:
             packed_attn[bias] = L_["attn"][bias]
@@ -87,8 +90,7 @@ def pack_dense_params(params: Params, cfg: ArchConfig) -> Params:
     packed_ffn = {}
     for name in ("w_gate", "w_up", "w_down"):
         if name in L_["ffn"]:
-            packed_ffn[name] = jax.vmap(kops.pack_int4_weights)(
-                L_["ffn"][name])
+            packed_ffn[name] = pack(L_["ffn"][name])
     out["layers"]["ffn"] = packed_ffn
     return out
 

@@ -80,7 +80,8 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.optim import adamw
     from repro.train.step import TrainConfig, make_train_step
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("llama3-1b").reduced(n_layers=2, d_model=64, vocab=128,
                                           n_heads=4, n_kv_heads=2,
                                           head_dim=16, d_ff=128)

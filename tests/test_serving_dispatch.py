@@ -58,9 +58,39 @@ def test_dispatched_path_matches_reference_bitwise(packed_setup, kv_bits):
         np.testing.assert_array_equal(np.asarray(lk), np.asarray(lr))
 
 
+@pytest.mark.parametrize("kv_bits", [None, 8])
+def test_dispatched_path_matches_reference_bitwise_bf16(kv_bits):
+    """The same contract on the paged path at the bf16 compute dtype of
+    the published configs. XLA may skip the bf16 rounding of a value it
+    fuses into an f32 consumer (excess precision), which a kernel operand
+    never skips, so both paths compile with that liberty off — as
+    `chip_smoke.py` compares them on the chip."""
+    cfg = get_config("llama3-1b").reduced(param_dtype="bfloat16",
+                                          compute_dtype="bfloat16")
+    packed = pack_dense_params(
+        build_model(cfg).init(jax.random.PRNGKey(0)), cfg)
+    qlm = QuantizedDenseLM(cfg, block_size=16, kv_bits=kv_bits)
+    args = (packed, jnp.asarray([TOKENS], jnp.int32), qlm.init_cache(2, 16),
+            jnp.asarray(0, jnp.int32), jnp.asarray([[1]], jnp.int32),
+            jnp.asarray([len(TOKENS)], jnp.int32))
+    outs = {}
+    for enabled in (True, False):
+        with kops.use_kernels(enabled):
+            fwd = jax.jit(qlm.forward_chunk).lower(*args).compile(
+                compiler_options={"xla_allow_excess_precision": False})
+            outs[enabled] = np.asarray(fwd(*args)[0])
+    np.testing.assert_array_equal(outs[True], outs[False])
+
+
 def test_prefill_matches_stepwise_decode(packed_setup):
     """Causal prefill must produce the same per-position logits and cache
-    as feeding the prompt token by token."""
+    as feeding the prompt token by token.
+
+    Not bit for bit: prefill multiplies S-row activations where decode
+    multiplies one row, and XLA:CPU sums the f32 contraction of an M-row
+    and a 1-row matmul in different orders (a plain `x @ w` differs in
+    most elements between the two), a few ulp apart. 1e-5 is two orders
+    above that and three below bf16 rounding of the same logits."""
     cfg, packed = packed_setup
     qlm = QuantizedDenseLM(cfg, block_size=16)
     cache = qlm.init_cache(1, 16)
@@ -70,7 +100,8 @@ def test_prefill_matches_stepwise_decode(packed_setup):
         step, cache = qlm.decode_step(
             packed, jnp.asarray([[t]], jnp.int32), cache,
             jnp.asarray(i, jnp.int32))
-        np.testing.assert_array_equal(np.asarray(pre[:, i]), np.asarray(step))
+        np.testing.assert_allclose(np.asarray(pre[:, i]), np.asarray(step),
+                                   rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("kv_bits", [None, 8, 4])
