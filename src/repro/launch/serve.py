@@ -27,12 +27,13 @@ page pressure), and `--drain-after N` exercises graceful shutdown:
 after N steps admission stops and the engine drains every tier empty.
 
 Observability: `--metrics-json PATH` writes the engine's schema-validated
-registry snapshot, `--trace PATH` records request lifecycles and fused
-dispatches as Chrome Trace JSON (open in https://ui.perfetto.dev), and
-`--probe-every K` samples the rotation-quality activation probes on the
-integer path. Every engine run ends with a one-line summary (tokens/s,
-per-token latency quantiles, peak pool utilization, admission wait)
-computed from the same registry snapshot.
+registry snapshot, `--trace PATH` records request lifecycles and the
+engine's step phases as Chrome Trace JSON (open in
+https://ui.perfetto.dev), and `--probe-every K` samples the
+rotation-quality activation probes on the integer path. Every engine run
+ends with a one-line summary (tokens/s, per-token latency quantiles, peak
+pool utilization, admission wait) computed from the same registry
+snapshot.
 
 `main` is a sequence of steps — `build_model_params`, `quantize`,
 `build_engine`, `run_engine` — that `chip_smoke.py` calls in the same

@@ -101,12 +101,27 @@ Telemetry: every engine counter lives in a `serve.telemetry`
 `MetricsRegistry` (`self.metrics`), including the robustness families —
 `engine.preemptions`, `engine.requests.cancelled/expired/failed`,
 `engine.replayed_prefill_tokens`, `engine.dispatch.faults`, and the
-live/peak page-utilization gauges. An optional `Tracer` records request
-lifecycles and per-dispatch wall times, and optional `QualityProbes`
-sample rotation-quality stats; both stay bit-path-neutral.
+live/peak page-utilization gauges, refreshed when a snapshot is taken.
+
+Step phases: `step()` runs as `engine.step` → `engine.admit`, then per
+dispatch `engine.prepare.<phase>` (page growth, block table, argument
+upload), `engine.dispatch.<phase>` (the jitted call until it returns:
+enqueue, plus trace and compile on a new shape), `engine.wait.<phase>`
+(the host blocks on the sampled tokens) and `engine.commit.<phase>`, then
+`engine.account` and `engine.stream`. Each phase is a
+`jax.profiler.TraceAnnotation`, so under the profiler the spans land on
+the device trace's clock; with a `Tracer` attached the same spans go to
+its engine track. Each fused program is named `engine_<phase>[_<variant>]`
+on the device. `engine.dispatch.new_shapes` counts dispatches whose
+program signature this engine had not dispatched before, and
+`engine.step.host_s` is each step's wall minus its waits: the time a
+synchronous engine leaves the chip idle. The `Tracer` also records
+request lifecycles, and optional `QualityProbes` sample rotation-quality
+stats; all of it stays bit-path-neutral.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -341,6 +356,9 @@ class ServeEngine:
         # jit cache for the fused phase dispatches, keyed on the kernels
         # flag (mirrors QuantizedDenseLM._jitted)
         self._jit_cache: dict = {}
+        # (program, shape) signatures dispatched so far: a new one traces
+        self._dispatched: set = set()
+        self._waited = 0.0                     # this step's wait phases, s
         # telemetry: the registry owns every counter the old plain-int
         # attributes held (read-only property views keep the old names
         # alive); tracer and quality probes are opt-in and bit-path-
@@ -927,7 +945,7 @@ class ServeEngine:
             f"{self.headroom_pages if self.admission == 'optimistic' else 0}"
             f"); blocked: {who}")
 
-    def _fused(self, name: str, impl, variant=None):
+    def _fused(self, name: str, impl, variant: tuple, shape: tuple):
         """One fused device dispatch per phase: forward (page writes +
         table walk inside) → sample trace into a single jit'd call, so
         per-step host overhead stays flat as the model grows. The pool is
@@ -936,8 +954,14 @@ class ServeEngine:
         kernels-enabled, variant) triple with the flag re-pinned inside
         the traced body, so `use_kernels(...)` scopes keep selecting the
         path they request instead of replaying the first-traced one;
-        `variant` keys host-known static choices (e.g. whether any row
-        needs top-k/p filtering)."""
+        `variant` names host-known static choices (e.g. `("filtered",)`
+        when a row needs top-k/p filtering), and the program is named
+        `engine_<phase>[_<variant>...]` on the device. `shape` holds the
+        dispatch's argument dimensions that vary (every other argument
+        shape is fixed for the engine's life). Returns the jitted program
+        and whether this (program, shape) signature is new to the engine,
+        i.e. the dispatch traces and then compiles or loads; new ones are
+        counted in `engine.dispatch.new_shapes`."""
         key = (name, kops.kernels_enabled(), variant)
         fn = self._jit_cache.get(key)
         if fn is None:
@@ -947,8 +971,30 @@ class ServeEngine:
                 with kops.use_kernels(enabled):
                     return impl(*args)
 
+            wrapped.__name__ = wrapped.__qualname__ = "_".join(
+                ("engine", name) + variant)
             fn = self._jit_cache[key] = jax.jit(wrapped, donate_argnums=(0,))
-        return fn
+        new = (key, shape) not in self._dispatched
+        if new:
+            self._dispatched.add((key, shape))
+            self.metrics.counter("engine.dispatch.new_shapes").inc()
+        return fn, new
+
+    def _span(self, name: str, annotation=jax.profiler.TraceAnnotation,
+              **args):
+        """A step phase as a host span on the profiler's clock (nearly
+        free while no profiler runs); with a `Tracer` attached, also on
+        its engine track, with the step number. Phase-level only: never
+        per request or row."""
+        if self.tracer is None:
+            return annotation(name, **args)
+        return self._traced_span(name, annotation, args)
+
+    @contextlib.contextmanager
+    def _traced_span(self, name, annotation, args):
+        with annotation(name, **args) as ann, self.tracer.span(
+                name, args={"step_num": self._step_index, **args}):
+            yield ann
 
     # ------------------------------------------------------------------
     # telemetry
@@ -974,9 +1020,11 @@ class ServeEngine:
                      "engine.swap.out", "engine.swap.in",
                      "engine.swap.bytes", "engine.swap.retries",
                      "engine.swap.fallbacks", "engine.requests.poisoned",
-                     "engine.stream.callback_errors"):
+                     "engine.stream.callback_errors",
+                     "engine.dispatch.new_shapes"):
             m.counter(name)
-        for name in ("engine.step.wall_s", "engine.step.budget_utilization",
+        for name in ("engine.step.wall_s", "engine.step.host_s",
+                     "engine.step.budget_utilization",
                      "engine.decode.batch_occupancy",
                      "engine.decode.token_latency_s",
                      "engine.admission.wait_s", "engine.request.e2e_s",
@@ -1006,17 +1054,7 @@ class ServeEngine:
             tree.n_pages if tree is not None else 0)
         m.gauge("engine.prefix.tree_nodes").set(
             tree.n_nodes if tree is not None else 0)
-        if tree is not None:
-            # the tree counts its own insert/evict traffic; mirror it as
-            # monotonic counters (same pattern as the kernel dispatch
-            # tallies in metrics_snapshot)
-            for name, n in (("engine.prefix.inserted_pages",
-                             tree.inserted_pages),
-                            ("engine.prefix.evicted_pages",
-                             tree.evicted_pages)):
-                c = m.counter(name)
-                if n > c.value:
-                    c.value = n
+        self._sync_prefix_counters()
         # host-tier occupancy: always emitted (zeros when no pool) so the
         # snapshot shape is policy-independent
         hp = self.kv.host_pool
@@ -1035,6 +1073,20 @@ class ServeEngine:
                 regs.peak_in_use)
             m.gauge("engine.register_slots.scrubbed").set(
                 self.kv.slots_scrubbed)
+
+    def _sync_prefix_counters(self):
+        """The tree counts its own insert/evict traffic; mirror it as
+        monotonic counters (same pattern as the kernel dispatch tallies in
+        metrics_snapshot), live at every step boundary."""
+        tree = self.prefix_cache
+        if tree is None:
+            return
+        m = self.metrics
+        for name, n in (("engine.prefix.inserted_pages", tree.inserted_pages),
+                        ("engine.prefix.evicted_pages", tree.evicted_pages)):
+            c = m.counter(name)
+            if n > c.value:
+                c.value = n
 
     def metrics_snapshot(self) -> dict:
         """Schema-versioned registry export (the shape
@@ -1240,88 +1292,97 @@ class ServeEngine:
             return state, lg, toks, mx, stats
         return state, lg, toks, mx
 
-    def _decode_once(self) -> list[EngineRequest]:
-        batch = self.decoding
-        b = self.max_seqs
+    def _decode_once(self) -> tuple[int, list[EngineRequest]]:
+        """Grow every decoding table, dispatch the batch, wait for its
+        sampled tokens and commit them; returns (rows dispatched,
+        requests finished)."""
         m = self.metrics
-        rids = [r.rid for r in batch] + [None] * (b - len(batch))
-        new_lens = [r.n_cached + 1 for r in batch]
-        if self.spec.kv:
-            n_cols = _next_pow2(max(
-                pages_for(r.n_cached + 1, self.kv.page_size) for r in batch))
-            bt = self.kv.block_table_array(rids, n_cols)
-            m.counter("engine.pages_walked").inc(
-                sum(pages_for(n, self.kv.page_size) for n in new_lens))
-            m.counter("engine.pages_walked_dense").inc(b * n_cols)
-        else:
-            bt = None
-        reg = self.kv.register_index_array(rids) if self.spec.register \
-            else None
-        tokens = jnp.asarray(
-            [[r.next_token] for r in batch] + [[0]] * (b - len(batch)),
-            jnp.int32)
-        fill = jnp.asarray([r.n_cached for r in batch]
-                           + [0] * (b - len(batch)), jnp.int32)
-        lens = jnp.asarray(new_lens + [0] * (b - len(batch)), jnp.int32)
-        rid_rows = jnp.asarray([r.rid for r in batch]
-                               + [0] * (b - len(batch)), jnp.int32)
-
-        temps = jnp.asarray([r.sampling.temperature for r in batch]
-                            + [0.0] * (b - len(batch)), jnp.float32)
-        top_ks = jnp.asarray([r.sampling.top_k for r in batch]
-                             + [0] * (b - len(batch)), jnp.int32)
-        top_ps = jnp.asarray([r.sampling.top_p for r in batch]
-                             + [1.0] * (b - len(batch)), jnp.float32)
-        filtered = self._wants_filtering(batch)
-        probe = (self.quality_probes is not None
-                 and self.quality_probes.should_probe())
-        m.histogram("engine.decode.batch_occupancy").observe(
-            len(batch) / self.max_seqs)
-        tr = self.tracer
-        ts0 = tr.ts() if tr else 0.0
-        out = self._fused(
-            "decode",
-            functools.partial(self._decode_impl, filtered=filtered,
-                              probe=probe),
-            variant=(filtered, probe))(
-            self.kv.state, self.adapter.params, self._base_key, bt, reg,
-            tokens, fill, lens, rid_rows, temps, top_ks, top_ps)
+        with self._span("engine.prepare.decode"):
+            self._grow_decode()
+            batch = list(self.decoding)
+            if not batch:
+                return 0, []
+            b = self.max_seqs
+            pad = b - len(batch)
+            rids = [r.rid for r in batch] + [None] * pad
+            new_lens = [r.n_cached + 1 for r in batch]
+            n_cols = 0
+            if self.spec.kv:
+                n_cols = _next_pow2(max(
+                    pages_for(n, self.kv.page_size) for n in new_lens))
+                bt = self.kv.block_table_array(rids, n_cols)
+                m.counter("engine.pages_walked").inc(
+                    sum(pages_for(n, self.kv.page_size) for n in new_lens))
+                m.counter("engine.pages_walked_dense").inc(b * n_cols)
+            else:
+                bt = None
+            reg = self.kv.register_index_array(rids) if self.spec.register \
+                else None
+            args = (
+                self.kv.state, self.adapter.params, self._base_key, bt, reg,
+                jnp.asarray([[r.next_token] for r in batch] + [[0]] * pad,
+                            jnp.int32),
+                jnp.asarray([r.n_cached for r in batch] + [0] * pad,
+                            jnp.int32),
+                jnp.asarray(new_lens + [0] * pad, jnp.int32),
+                jnp.asarray([r.rid for r in batch] + [0] * pad, jnp.int32),
+                jnp.asarray([r.sampling.temperature for r in batch]
+                            + [0.0] * pad, jnp.float32),
+                jnp.asarray([r.sampling.top_k for r in batch] + [0] * pad,
+                            jnp.int32),
+                jnp.asarray([r.sampling.top_p for r in batch] + [1.0] * pad,
+                            jnp.float32))
+            filtered = self._wants_filtering(batch)
+            probe = (self.quality_probes is not None
+                     and self.quality_probes.should_probe())
+            m.histogram("engine.decode.batch_occupancy").observe(
+                len(batch) / self.max_seqs)
+            fn, new = self._fused(
+                "decode",
+                functools.partial(self._decode_impl, filtered=filtered,
+                                  probe=probe),
+                tuple(n for n, on in (("filtered", filtered), ("probe", probe))
+                      if on), (n_cols,))
+        with self._span("engine.dispatch.decode", rows=len(batch),
+                        n_cols=n_cols, new_shape=new):
+            out = fn(*args)
         if probe:
             self.kv.state, logits, toks, mx, stats = out
         else:
             (self.kv.state, logits, toks, mx), stats = out, None
-        if tr:
-            jax.block_until_ready((self.kv.state, toks))
-            tr.complete("dispatch.decode", ts0, tr.ts() - ts0,
-                        args={"rows": len(batch), "probe": probe})
-        if stats is not None:
-            self.quality_probes.record(stats)
-        toks = np.asarray(toks)
-        finite = np.isfinite(np.asarray(mx))
-        finished = []
-        for i, req in enumerate(list(batch)):
-            if not finite[i]:
-                # poisoned adapter output (NaN/Inf logits): terminate
-                # only this row — its sampled token is garbage and must
-                # not enter the stream; other rows are independent
-                req.failed = (f"non-finite logits at stream position "
-                              f"{req.n_cached} (poisoned model output)")
-                m.counter("engine.requests.poisoned").inc()
-                self._terminate(req, "failed")   # returned via _terminal
-                continue
-            req.n_cached += 1
-            req.generated.append(int(toks[i]))
-            req.next_token = int(toks[i])
-            if self.record_logits:
-                req.step_logits.append(np.asarray(logits[i], np.float32))
-            m.counter("engine.decode_tokens").inc()
-            m.counter("engine.generated_tokens").inc()
-            self._check_stop(req)
-            if req.done:
-                self.decoding.remove(req)
-                self._finish(req)
-                finished.append(req)
-        return finished
+        with self._span("engine.wait.decode"):
+            t = time.perf_counter()
+            toks = np.asarray(toks)
+            finite = np.isfinite(np.asarray(mx))
+            if stats is not None:
+                self.quality_probes.record(stats)
+            self._waited += time.perf_counter() - t
+        with self._span("engine.commit.decode"):
+            finished = []
+            for i, req in enumerate(batch):
+                if not finite[i]:
+                    # poisoned adapter output (NaN/Inf logits): terminate
+                    # only this row — its sampled token is garbage and
+                    # must not enter the stream; other rows are
+                    # independent
+                    req.failed = (f"non-finite logits at stream position "
+                                  f"{req.n_cached} (poisoned model output)")
+                    m.counter("engine.requests.poisoned").inc()
+                    self._terminate(req, "failed")  # returned via _terminal
+                    continue
+                req.n_cached += 1
+                req.generated.append(int(toks[i]))
+                req.next_token = int(toks[i])
+                if self.record_logits:
+                    req.step_logits.append(np.asarray(logits[i], np.float32))
+                m.counter("engine.decode_tokens").inc()
+                m.counter("engine.generated_tokens").inc()
+                self._check_stop(req)
+                if req.done:
+                    self.decoding.remove(req)
+                    self._finish(req)
+                    finished.append(req)
+        return len(batch), finished
 
     # ------------------------------------------------------------------
     # chunked prefill
@@ -1364,89 +1425,100 @@ class ServeEngine:
         m = self.metrics
         real = min(self.prefill_chunk, budget, len(stream) - start)
         padded = _next_pow2(real)
-        if self.spec.kv:
-            while True:
-                try:
-                    self._ensure(req.rid, start + real)
-                    break
-                except MemoryError:
-                    self._reclaim()
-                    if req not in self.prefilling:
-                        return 0, []    # the head itself was preempted
-            n_cols = _next_pow2(pages_for(start + padded, self.kv.page_size))
-            bt = self.kv.block_table_array([req.rid], n_cols)
-            m.counter("engine.pages_walked").inc(
-                pages_for(start + real, self.kv.page_size))
-            m.counter("engine.pages_walked_dense").inc(n_cols)
-        else:
-            bt = None
-        reg = self.kv.register_index_array([req.rid]) if self.spec.register \
-            else None
-        self._maybe_dispatch_fault("prefill")
-
-        # every device-side shape depends only on (padded, n_cols), both
-        # powers of two, so prefill compiles a bounded set of variants;
-        # `last` (= real - 1) rides along as a traced scalar
-        chunk = stream[start:start + real] + [0] * (padded - real)
-        filtered = self._wants_filtering([req])
-        tr = self.tracer
-        ts0 = tr.ts() if tr else 0.0
-        self.kv.state, last, tok, mx = self._fused(
-            "prefill",
-            functools.partial(self._prefill_impl, filtered=filtered),
-            variant=filtered)(
-            self.kv.state, self.adapter.params, self._base_key, bt, reg,
-            jnp.asarray([chunk], jnp.int32), jnp.asarray(start, jnp.int32),
-            jnp.asarray(real - 1, jnp.int32),
-            jnp.asarray([start + real], jnp.int32),
-            jnp.asarray([req.rid], jnp.int32),
-            jnp.asarray([req.sampling.temperature], jnp.float32),
-            jnp.asarray([req.sampling.top_k], jnp.int32),
-            jnp.asarray([req.sampling.top_p], jnp.float32))
-        if tr:
-            jax.block_until_ready((self.kv.state, tok))
-            tr.complete("dispatch.prefill", ts0, tr.ts() - ts0,
-                        args={"rid": req.rid, "tokens": real,
-                              "padded": padded})
-        if not np.isfinite(float(mx)):
-            # poisoned adapter output mid-prefill: the chunk's logits are
-            # garbage, so the request cannot continue — terminate it
-            # alone (the single-sequence dispatch touched no other state)
-            req.failed = (f"non-finite logits in prefill at stream "
-                          f"position {start + real - 1} "
-                          f"(poisoned model output)")
-            m.counter("engine.requests.poisoned").inc()
-            self._terminate(req, "failed")
-            return real, []
-
-        req.n_cached = start + real
-        m.counter("engine.prefill_tokens").inc(real)
-        if req.n_preempted > 0:
-            # replay cost = rows actually recomputed (a prefix-tree hit
-            # at re-admission already skipped the resident ones)
-            m.counter("engine.replayed_prefill_tokens").inc(real)
-        m.histogram("engine.prefill.chunk_tokens").observe(real)
-        finished = []
-        if req.n_cached == len(stream):
-            # stream fully cached: the fused call already sampled the
-            # next token from the last real position's logits (for a
-            # replay, this continues the original sequence exactly — the
-            # (rid, position) key is the one the undisturbed decode used)
-            self.prefilling.remove(req)
-            req.generated.append(int(tok))
-            req.next_token = int(tok)
-            if self.record_logits:
-                req.step_logits.append(np.asarray(last, np.float32))
-            m.counter("engine.generated_tokens").inc()
-            if tr:
-                tr.end("prefill", pid=PID_REQUESTS, tid=req.rid)
-                tr.begin("decode", pid=PID_REQUESTS, tid=req.rid)
-            self._check_stop(req)
-            if req.done:
-                self._finish(req)
-                finished.append(req)
+        with self._span("engine.prepare.prefill"):
+            n_cols = 0
+            if self.spec.kv:
+                while True:
+                    try:
+                        self._ensure(req.rid, start + real)
+                        break
+                    except MemoryError:
+                        self._reclaim()
+                        if req not in self.prefilling:
+                            return 0, []   # the head itself was preempted
+                n_cols = _next_pow2(pages_for(start + padded,
+                                              self.kv.page_size))
+                bt = self.kv.block_table_array([req.rid], n_cols)
+                m.counter("engine.pages_walked").inc(
+                    pages_for(start + real, self.kv.page_size))
+                m.counter("engine.pages_walked_dense").inc(n_cols)
             else:
-                self.decoding.append(req)
+                bt = None
+            reg = self.kv.register_index_array([req.rid]) \
+                if self.spec.register else None
+            self._maybe_dispatch_fault("prefill")
+
+            # every device-side shape depends only on (padded, n_cols),
+            # both powers of two, so prefill compiles a bounded set of
+            # variants; `last` (= real - 1) rides along as a traced scalar
+            chunk = stream[start:start + real] + [0] * (padded - real)
+            args = (
+                self.kv.state, self.adapter.params, self._base_key, bt, reg,
+                jnp.asarray([chunk], jnp.int32), jnp.asarray(start, jnp.int32),
+                jnp.asarray(real - 1, jnp.int32),
+                jnp.asarray([start + real], jnp.int32),
+                jnp.asarray([req.rid], jnp.int32),
+                jnp.asarray([req.sampling.temperature], jnp.float32),
+                jnp.asarray([req.sampling.top_k], jnp.int32),
+                jnp.asarray([req.sampling.top_p], jnp.float32))
+            filtered = self._wants_filtering([req])
+            fn, new = self._fused(
+                "prefill",
+                functools.partial(self._prefill_impl, filtered=filtered),
+                ("filtered",) if filtered else (), (padded, n_cols))
+        with self._span("engine.dispatch.prefill", tokens=real,
+                        padded=padded, n_cols=n_cols, new_shape=new):
+            self.kv.state, last, tok, mx = fn(*args)
+        complete = start + real == len(stream)
+        with self._span("engine.wait.prefill"):
+            t = time.perf_counter()
+            mx = float(mx)
+            if complete:
+                tok = int(tok)
+            self._waited += time.perf_counter() - t
+        with self._span("engine.commit.prefill"):
+            if not np.isfinite(mx):
+                # poisoned adapter output mid-prefill: the chunk's logits
+                # are garbage, so the request cannot continue — terminate
+                # it alone (the single-sequence dispatch touched no other
+                # state)
+                req.failed = (f"non-finite logits in prefill at stream "
+                              f"position {start + real - 1} "
+                              f"(poisoned model output)")
+                m.counter("engine.requests.poisoned").inc()
+                self._terminate(req, "failed")
+                return real, []
+
+            req.n_cached = start + real
+            m.counter("engine.prefill_tokens").inc(real)
+            if req.n_preempted > 0:
+                # replay cost = rows actually recomputed (a prefix-tree hit
+                # at re-admission already skipped the resident ones)
+                m.counter("engine.replayed_prefill_tokens").inc(real)
+            m.histogram("engine.prefill.chunk_tokens").observe(real)
+            finished = []
+            if complete:
+                # stream fully cached: the fused call already sampled the
+                # next token from the last real position's logits (for a
+                # replay, this continues the original sequence exactly —
+                # the (rid, position) key is the one the undisturbed
+                # decode used)
+                self.prefilling.remove(req)
+                req.generated.append(tok)
+                req.next_token = tok
+                if self.record_logits:
+                    req.step_logits.append(np.asarray(last, np.float32))
+                m.counter("engine.generated_tokens").inc()
+                if self.tracer:
+                    self.tracer.end("prefill", pid=PID_REQUESTS, tid=req.rid)
+                    self.tracer.begin("decode", pid=PID_REQUESTS,
+                                      tid=req.rid)
+                self._check_stop(req)
+                if req.done:
+                    self._finish(req)
+                    finished.append(req)
+                else:
+                    self.decoding.append(req)
         return real, finished
 
     # ------------------------------------------------------------------
@@ -1459,55 +1531,65 @@ class ServeEngine:
         failed — check `req.outcome`)."""
         m = self.metrics
         t0 = time.perf_counter()
+        self._waited = 0.0
         gen0 = m.counter("engine.generated_tokens").value
-        self._apply_faults()
-        self._expire_deadlines()
-        self._admit()
-        self._check_stalled()
-        finished = []
-        budget = self.token_budget
-        spent = 0
-        if self.decoding:
-            try:
-                self._maybe_dispatch_fault("decode")
-                self._grow_decode()
-                if self.decoding:
-                    budget -= len(self.decoding)
-                    spent += len(self.decoding)
-                    finished.extend(self._decode_once())
-            except DispatchFault:
-                m.counter("engine.dispatch.faults").inc()
-        # `guard` bounds the zero-progress retries a preempted prefill
-        # head can cause within one step (each retry strictly shrinks
-        # the active set, so this terminates regardless)
-        guard = len(self.prefilling) + 1
-        while budget > 0 and self.prefilling and guard > 0:
-            try:
-                used, fin = self._prefill_once(budget)
-            except DispatchFault:
-                m.counter("engine.dispatch.faults").inc()
-                break
-            budget -= used
-            spent += used
-            finished.extend(fin)
-            if used == 0:
-                guard -= 1
+        with self._span("engine.step", jax.profiler.StepTraceAnnotation,
+                        step_num=self._step_index) as span:
+            with self._span("engine.admit"):
+                self._apply_faults()
+                self._expire_deadlines()
+                self._admit()
+                self._check_stalled()
+            finished = []
+            budget = self.token_budget
+            spent = rows = chunks = 0
+            if self.decoding:
+                try:
+                    self._maybe_dispatch_fault("decode")
+                    rows, fin = self._decode_once()
+                    budget -= rows
+                    spent += rows
+                    finished.extend(fin)
+                except DispatchFault:
+                    m.counter("engine.dispatch.faults").inc()
+            # `guard` bounds the zero-progress retries a preempted prefill
+            # head can cause within one step (each retry strictly shrinks
+            # the active set, so this terminates regardless)
+            guard = len(self.prefilling) + 1
+            while budget > 0 and self.prefilling and guard > 0:
+                try:
+                    used, fin = self._prefill_once(budget)
+                except DispatchFault:
+                    m.counter("engine.dispatch.faults").inc()
+                    break
+                budget -= used
+                spent += used
+                finished.extend(fin)
+                if used == 0:
+                    guard -= 1
+                else:
+                    chunks += 1
+            with self._span("engine.account"):
+                m.counter("engine.steps").inc()
+                wall = time.perf_counter() - t0
+                m.histogram("engine.step.wall_s").observe(wall)
+                m.histogram("engine.step.host_s").observe(wall - self._waited)
+                m.histogram("engine.step.budget_utilization").observe(
+                    spent / self.token_budget)
+                # each token generated this step inherits the step's wall
+                # time (the host read of the sampled tokens already forced
+                # the device sync, so the wall is real)
+                lat = m.histogram("engine.decode.token_latency_s")
+                for _ in range(m.counter("engine.generated_tokens").value
+                               - gen0):
+                    lat.observe(wall)
+                self._sync_prefix_counters()
+                finished.extend(self._terminal)
+                self._terminal.clear()
+            with self._span("engine.stream"):
+                self._flush_streams(finished)
+            span.set_metadata(rows=rows, chunks=chunks)
         self._step_index += 1
-        m.counter("engine.steps").inc()
-        wall = time.perf_counter() - t0
-        m.histogram("engine.step.wall_s").observe(wall)
-        m.histogram("engine.step.budget_utilization").observe(
-            spent / self.token_budget)
-        # each token generated this step inherits the step's wall time
-        # (np.asarray on the sampled tokens already forced the device
-        # sync, so the wall is real even without tracing)
-        lat = m.histogram("engine.decode.token_latency_s")
-        for _ in range(m.counter("engine.generated_tokens").value - gen0):
-            lat.observe(wall)
-        self._update_gauges()
-        finished.extend(self._terminal)
-        self._terminal.clear()
-        self._flush_streams(finished)
         return finished
 
     def _flush_streams(self, finished: list[EngineRequest]):

@@ -19,12 +19,18 @@ Metric taxonomy (schema version {ver}; canonical list + validation in
   ``engine.pages_walked`` / ``engine.pages_walked_dense`` (ragged
   early-exit vs padded full-table walk, per attention dispatch),
   ``engine.requests.{{submitted,admitted,finished,stop_hits}}``,
-  ``engine.admission.blocked`` (head-of-line blocked on pages).
+  ``engine.admission.blocked`` (head-of-line blocked on pages),
+  ``engine.dispatch.new_shapes`` (dispatches of a program signature the
+  engine had not dispatched before: each traces, then compiles or
+  loads).
   Gauges: ``engine.pages.{{capacity,in_use,peak_in_use,reserved,
   scrubbed}}`` (allocator levels + high-water mark + scrub total),
   ``engine.register_slots.*`` (same, SSM/hybrid specs only),
   ``engine.queue.depth``, ``engine.batch.{{decoding,prefilling}}``.
-  Histograms: ``engine.step.wall_s``,
+  Gauges are refreshed from the live bookkeeping when a snapshot is
+  taken, not every step.
+  Histograms: ``engine.step.wall_s``, ``engine.step.host_s`` (the step
+  wall minus its device waits: the host's own time),
   ``engine.step.budget_utilization`` (tokens spent / token budget),
   ``engine.decode.batch_occupancy`` (decode rows / max_seqs, observed
   per decode dispatch), ``engine.decode.token_latency_s`` (each
@@ -36,8 +42,9 @@ Metric taxonomy (schema version {ver}; canonical list + validation in
   tallies mirrored from `repro.kernels.ops` at snapshot time. These
   count *Python-level* calls: once per jit trace for traced callers,
   once per call for eager ones — the path tag records which backend the
-  trace baked in (wall time for the fused serving dispatches lives in
-  the trace spans, where it can be measured honestly).
+  trace baked in (device time of the fused serving programs,
+  `engine_decode*` / `engine_prefill*`, lives in a `jax.profiler` trace
+  beside the engine's step-phase spans).
 * ``quality.*`` — rotation-quality probes (int4 path, sampled every K
   decode dispatches): ``quality.<stat>`` pooled histograms and
   ``quality.layer<NN>.<stat>`` per-layer latest-value gauges for
@@ -48,7 +55,8 @@ Metric taxonomy (schema version {ver}; canonical list + validation in
 
 Snapshots are versioned dicts (`MetricsRegistry.snapshot()`), mergeable
 across processes (`merge`: counters add, histogram buckets add) for the
-multi-host roll-up. Traces are Chrome Trace Event Format JSON that opens
+multi-host roll-up. Traces (request lifecycles and the engine's step
+phases) are Chrome Trace Event Format JSON that opens
 directly in Perfetto (`Tracer.save`). `python -m
 repro.serve.telemetry.check` validates both artifact kinds in CI.
 """

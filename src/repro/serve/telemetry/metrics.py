@@ -37,7 +37,9 @@ import math
 # v4: tiered-paging taxonomy — host-swap traffic counters
 # (out/in/bytes/retries/fallbacks) + host-tier occupancy gauges, plus
 # the poisoned-request and stream-callback-error degradation counters
-SCHEMA_VERSION = 4
+# v5: step-phase taxonomy — the engine.dispatch.new_shapes counter and
+# the engine.step.host_s histogram
+SCHEMA_VERSION = 5
 
 
 class Counter:

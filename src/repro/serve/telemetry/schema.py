@@ -52,6 +52,10 @@ REQUIRED_COUNTERS = (
     "engine.swap.fallbacks",
     "engine.requests.poisoned",
     "engine.stream.callback_errors",
+    # dispatches whose (program, variant, argument shapes) signature the
+    # engine had not dispatched before: each traces, then compiles or
+    # loads from the persistent cache
+    "engine.dispatch.new_shapes",
 )
 
 REQUIRED_GAUGES = (
@@ -78,6 +82,8 @@ REQUIRED_GAUGES = (
 
 REQUIRED_HISTOGRAMS = (
     "engine.step.wall_s",
+    # step wall minus its device waits: the host's own time per step
+    "engine.step.host_s",
     "engine.step.budget_utilization",
     "engine.decode.batch_occupancy",
     "engine.decode.token_latency_s",

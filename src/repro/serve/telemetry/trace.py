@@ -1,4 +1,5 @@
-"""Request/dispatch tracing in Chrome Trace Event Format (Perfetto-ready).
+"""Request and engine-phase tracing in Chrome Trace Event Format
+(Perfetto-ready).
 
 `Tracer` records two families of events, stdlib-only and append-only so
 the hot loop pays one list append per event:
@@ -9,16 +10,17 @@ the hot loop pays one list append per event:
   `decode` (first token → finish) spans as B/E pairs, plus instant
   events for page/slot allocations. One row per request in the Perfetto
   track view.
-* **Engine dispatches** (pid `PID_ENGINE`, tid 0): each fused
-  decode/prefill device dispatch as a complete ("X") event. The duration
-  is wall time measured around the dispatch with
-  `jax.block_until_ready` on its outputs (the *scheduler* blocks, this
-  module never imports jax) — so with tracing on, per-dispatch device
-  time is real, at the cost of serializing host/device overlap. Tracing
-  is therefore off by default and must stay bit-path-neutral: it may
-  only ever add host-side timing/blocking, never change dispatch
-  shapes, argument values, or PRNG key consumption (regression-tested
-  by the engine parity tests).
+* **Engine step phases** (pid `PID_ENGINE`, tid 0): the spans the
+  scheduler also writes to the profiler — `engine.step` and, nested in
+  it, `engine.admit`, `engine.{prepare,dispatch,wait,commit}.<phase>`,
+  `engine.account`, `engine.stream` — as B/E pairs carrying the same
+  names and `step_num`, so this file and a `jax.profiler` trace match
+  step by step. The profiler's copy sits on the device trace's clock;
+  this one covers the request tracks, which span several steps. Nothing
+  blocks on the device for tracing (the `wait` phases are the engine's
+  own reads of its sampled tokens), so tracing stays bit-path-neutral:
+  it never changes dispatch shapes, argument values or PRNG key
+  consumption (regression-tested by the engine parity tests).
 
 Timestamps are microseconds relative to tracer construction
 (`time.perf_counter_ns`-derived, monotonic). `save()` writes the
@@ -74,16 +76,6 @@ class Tracer:
     def instant(self, name: str, *, pid: int = PID_ENGINE, tid: int = 0,
                 args: dict | None = None):
         ev = {"name": name, "ph": "i", "ts": self.ts(), "s": "t",
-              "pid": pid, "tid": tid}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
-
-    def complete(self, name: str, ts: float, dur: float, *,
-                 pid: int = PID_ENGINE, tid: int = 0,
-                 args: dict | None = None):
-        """An "X" event: `ts`/`dur` in µs on this tracer's clock."""
-        ev = {"name": name, "ph": "X", "ts": ts, "dur": max(dur, 0.0),
               "pid": pid, "tid": tid}
         if args:
             ev["args"] = args

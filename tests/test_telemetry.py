@@ -7,6 +7,7 @@ import importlib.util
 import json
 import math
 import pathlib
+import re
 import sys
 
 import jax
@@ -19,10 +20,12 @@ from repro.models.transformer import build_model
 from repro.serve.engine import (EngineRequest, SamplingParams, ServeEngine,
                                 as_servable)
 from repro.serve.quantized import QuantizedDenseLM, pack_dense_params
-from repro.serve.telemetry import (PROBE_STATS, SCHEMA_VERSION, Histogram,
-                                   MetricsRegistry, QualityProbes, Tracer,
-                                   validate_snapshot, validate_trace)
+from repro.serve.telemetry import (PID_ENGINE, PROBE_STATS, SCHEMA_VERSION,
+                                   Histogram, MetricsRegistry, QualityProbes,
+                                   Tracer, validate_snapshot, validate_trace)
 from repro.serve.telemetry.metrics import Counter
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 PROMPTS = [[3, 14, 15, 92, 6], [53, 58, 9], [7, 9, 3, 23, 84, 62]]
 MAX_NEW = 3
@@ -170,7 +173,8 @@ def test_tracer_emits_valid_chrome_trace(tmp_path):
     tr.begin("queued", pid=2, tid=1)
     tr.end("queued", pid=2, tid=1)
     tr.instant("alloc_pages", pid=2, tid=1, args={"pages": 2})
-    tr.complete("dispatch.decode", tr.ts(), 12.5)
+    with tr.span("engine.step", args={"step_num": 0}):
+        pass
     tr.end("request", pid=2, tid=1)
     n = validate_trace(tr.to_dict())
     assert n == len(tr.events)
@@ -267,22 +271,42 @@ def test_engine_snapshot_and_trace_valid(stack):
     assert h["engine.request.e2e_s"]["count"] == len(PROMPTS)
     # the kernel dispatch tallies were mirrored in
     assert any(k.startswith("kernels.dispatch.") for k in c)
-    # every fused dispatch left an "X" event; every request a lifecycle
+    assert c["engine.dispatch.new_shapes"] > 0
+    assert h["engine.step.host_s"]["count"] == c["engine.steps"]
+    assert h["engine.step.host_s"]["sum"] < h["engine.step.wall_s"]["sum"]
+    # the engine track holds every step's phases as spans numbered by
+    # step, with no device-synchronised dispatch timing left; every
+    # request left a lifecycle
     evs = tr.to_dict()["traceEvents"]
-    assert sum(e["ph"] == "X" for e in evs) > 0
+    assert not any(e["ph"] == "X" for e in evs)
+    phases = [e for e in evs if e["ph"] == "B" and e["pid"] == PID_ENGINE]
+    steps = [e["args"]["step_num"] for e in phases
+             if e["name"] == "engine.step"]
+    assert steps == list(range(c["engine.steps"]))
+    assert {e["name"] for e in phases} == {
+        "engine.step", "engine.admit", "engine.account", "engine.stream"} | {
+        f"engine.{p}.{ph}" for p in ("prepare", "dispatch", "wait", "commit")
+        for ph in ("decode", "prefill")}
+    for e in phases:
+        if e["name"].startswith("engine.dispatch."):
+            assert {"n_cols", "new_shape"} <= set(e["args"])
     assert sum(e["ph"] == "B" and e["name"] == "request" for e in evs) \
         == len(PROMPTS)
 
 
-def test_tracing_is_bit_path_neutral(stack):
-    """Same tokens AND bit-identical recorded logits with tracing on."""
+def test_tracing_is_bit_path_neutral(stack, tmp_path):
+    """Same tokens AND bit-identical recorded logits with tracing on, and
+    with the profiler recording the engine's step spans too."""
     cfg, model, params, _ = stack
     _, plain = _run(as_servable(model, params))
     _, traced = _run(as_servable(model, params), tracer=Tracer())
-    for rid in plain:
-        assert traced[rid].generated == plain[rid].generated
-        for a, b in zip(traced[rid].step_logits, plain[rid].step_logits):
-            assert np.array_equal(a, b)
+    with jax.profiler.trace(str(tmp_path)):
+        _, profiled = _run(as_servable(model, params), tracer=Tracer())
+    for run in (traced, profiled):
+        for rid in plain:
+            assert run[rid].generated == plain[rid].generated
+            for a, b in zip(run[rid].step_logits, plain[rid].step_logits):
+                assert np.array_equal(a, b)
 
 
 def test_probes_are_bit_path_neutral_int4(stack):
@@ -313,6 +337,164 @@ def test_probes_are_bit_path_neutral_int4(stack):
     # rate in [0, 1]
     assert snap["histograms"]["quality.l1_imbalance_post"]["min"] >= 1.0
     assert 0.0 <= snap["histograms"]["quality.sat_rate"]["max"] <= 1.0
+
+
+def _spans():
+    """The benchmark's reduction of the engine's profiler spans."""
+    bench = str(ROOT / "perfbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from bench import spans, tracefile
+    return spans, tracefile
+
+
+# the phases of one step, in order: a prefill prepare may end the chunk
+# loop without a dispatch (its head was preempted)
+PHASE_ORDER = re.compile(
+    r"engine\.admit"
+    r"( engine\.prepare\.decode engine\.dispatch\.decode"
+    r" engine\.wait\.decode engine\.commit\.decode)?"
+    r"( engine\.prepare\.prefill( engine\.dispatch\.prefill"
+    r" engine\.wait\.prefill engine\.commit\.prefill)?)*"
+    r" engine\.account engine\.stream")
+
+
+def test_profiler_step_phases_nest_in_order(stack, tmp_path):
+    """Under the profiler (CPU backend) every engine step is one
+    `engine.step` span holding its phases in order, inside it, without
+    overlap; its `rows` arg is the number of decoding requests."""
+    spans, tracefile = _spans()
+    cfg, model, params, _ = stack
+    eng = ServeEngine(as_servable(model, params), n_pages=33, page_size=8,
+                      max_seqs=2, prefill_chunk=4)
+    for rid, p in enumerate(PROMPTS):
+        eng.submit(EngineRequest(rid=rid, prompt=list(p),
+                                 sampling=SamplingParams(max_new=MAX_NEW)))
+    decoding = []
+    with jax.profiler.trace(str(tmp_path)):
+        while eng.queue or eng.active:
+            decoding.append(len(eng.decoding))
+            eng.step()
+    ex = spans.extract(tracefile.find_xplane(tmp_path))
+    steps = spans.steps(ex)
+    assert [s[3]["step_num"] for s, _ in steps] == list(range(len(decoding)))
+    for (step, children), rows in zip(steps, decoding):
+        assert step[3]["rows"] == rows
+        assert step[3]["chunks"] == sum(
+            c[0] == "engine.dispatch.prefill" for c in children)
+        seq = " ".join(c[0] for c in children)
+        assert PHASE_ORDER.fullmatch(seq), seq
+        end = step[1]
+        for c in children:
+            assert step[1] <= c[1] and c[1] + c[2] <= step[1] + step[2]
+            assert c[1] >= end          # no overlap, in start order
+            end = c[1] + c[2]
+        dispatch = [c for c in children if c[0] == "engine.dispatch.decode"]
+        assert [c[3]["rows"] for c in dispatch] == ([rows] if rows else [])
+    red = spans.reduce(ex, 1.0)
+    assert red["n_steps"] == len(decoding)
+    assert red["n_decode_steps"] > 0 and red["host_ms"] > 0
+
+
+def test_span_reduction_hand_made():
+    """Host time is a decode-only step minus its waits; host-bound idle is
+    the window outside the union of device ops and wait spans (interval
+    intersection); device time is the mean `engine_decode*` module."""
+    spans, _ = _spans()
+    step = {"step_num": 0, "rows": 2, "chunks": 0}
+    ex = {"spans": [
+        ["engine.step", 0, 100, step], ["engine.admit", 0, 5, {}],
+        ["engine.prepare.decode", 5, 15, {}],
+        ["engine.dispatch.decode", 20, 5, {"rows": 2}],
+        ["engine.wait.decode", 25, 60, {}],
+        ["engine.commit.decode", 85, 5, {}], ["engine.account", 90, 5, {}],
+        ["engine.stream", 95, 5, {}],
+        ["engine.step", 100, 100, dict(step, step_num=1, chunks=1)],
+        ["engine.dispatch.decode", 120, 5, {"rows": 2}],
+        ["engine.wait.decode", 125, 20, {}],
+        ["engine.dispatch.prefill", 145, 5, {"tokens": 8}],
+        ["engine.wait.prefill", 150, 30, {}]],
+        "devices": [{"plane": "/device:TPU:0",
+                     "ops": [[22, 50], [80, 10], [125, 15], [160, 15]],
+                     "modules": [["jit_engine_decode(1)", 22, 68],
+                                 ["jit_engine_decode(1)", 125, 15],
+                                 ["jit_engine_prefill(2)", 160, 15]]}]}
+    r = spans.reduce(ex, 200e-9)
+    assert r["n_steps"] == 2 and r["n_decode_steps"] == 1
+    assert r["host_ms"] == pytest.approx(40e-6)
+    assert r["wait_ms"] == pytest.approx(60e-6)
+    assert r["busy_s"] == pytest.approx(90e-9)
+    # covered: [22, 90] by ops and the decode wait, [125, 145] and
+    # [150, 180] by waits and ops: 68 + 20 + 30 of 200
+    assert r["idle_host_s"] == pytest.approx(82e-9)
+    assert r["device_ms"] == pytest.approx(41.5e-6)
+
+
+def test_span_reduction_on_recorded_chip_trace():
+    """The reduction pinned on a slice of a `granite-3-2b.batch` trace
+    recorded on a TPU v5e (four 64-row decode steps,
+    `perfbench/tests/data/engine_trace_sample.json`, made by
+    `spans.sample`): the host's own time, the host-bound idle share and
+    the decode program's device time, and host-bound idle within the
+    device's idle."""
+    spans, _ = _spans()
+    ex = json.loads((ROOT / "perfbench" / "tests" / "data"
+                     / "engine_trace_sample.json").read_text())
+    w = ex["window_s"]
+    r = spans.reduce(ex, w)
+    assert r["n_steps"] == r["n_decode_steps"] == 4
+    assert r["host_ms"] == pytest.approx(33.737302)
+    assert 100 * r["idle_host_s"] / w == pytest.approx(9.344853884285508)
+    assert r["device_ms"] == pytest.approx(315.86269175)
+    device_idle = 100 * (1 - r["busy_s"] / w)
+    assert device_idle == pytest.approx(10.246706755877166)
+    assert 0 < 100 * r["idle_host_s"] / w <= device_idle
+    # a step is its host time and its wait, and the decode program runs
+    # inside the wait
+    assert r["host_ms"] + r["wait_ms"] == pytest.approx(
+        1e3 * w / 4, rel=0.01)
+    assert r["device_ms"] < r["wait_ms"]
+
+
+def test_new_shapes_counts_distinct_dispatch_signatures(stack):
+    """`engine.dispatch.new_shapes` counts each (program, shape) signature
+    once — the number of programs jit traced — and does not grow when the
+    same traffic repeats its shapes."""
+    cfg, model, params, _ = stack
+    eng, _ = _run(as_servable(model, params))
+
+    def traced():
+        return sum(fn._cache_size() for fn in eng._jit_cache.values())
+    n = eng.metrics_snapshot()["counters"]["engine.dispatch.new_shapes"]
+    assert n == traced() > 2
+    eng.reset_metrics()
+    for rid, p in enumerate(PROMPTS):
+        eng.submit(EngineRequest(rid=100 + rid, prompt=list(p),
+                                 sampling=SamplingParams(max_new=MAX_NEW)))
+    eng.run()
+    assert eng.metrics_snapshot()["counters"][
+        "engine.dispatch.new_shapes"] == 0
+    assert traced() == n
+
+
+def test_fused_programs_are_named(stack, monkeypatch):
+    """The lowered decode and prefill programs are modules named
+    `jit_engine_decode` / `jit_engine_prefill`, not `jit(wrapped)`."""
+    cfg, model, params, _ = stack
+    fused = ServeEngine._fused
+    texts = {}
+
+    def spy_fused(self, name, impl, variant, shape):
+        fn, new = fused(self, name, impl, variant, shape)
+
+        def spy(*args):
+            texts.setdefault(name, fn.lower(*args).as_text())
+            return fn(*args)
+        return spy, new
+    monkeypatch.setattr(ServeEngine, "_fused", spy_fused)
+    _run(as_servable(model, params), prompts=PROMPTS[:1])
+    assert "module @jit_engine_decode " in texts["decode"]
+    assert "module @jit_engine_prefill " in texts["prefill"]
 
 
 def test_probes_rejected_on_dense_adapter(stack):
