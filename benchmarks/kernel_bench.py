@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.kernels.paged_attention import pack_pages
 
 try:
     from .common import ragged_paged_batch
@@ -158,10 +159,9 @@ def paged_attention_bench(*, smoke: bool, iters: int = 5):
         n_cols = ctx // page_size
         lengths, n_pages, table, positions = ragged_paged_batch(
             batch, ctx, page_size)
-        kv = {"k": jnp.asarray(rng.standard_normal(
-                  (n_pages, page_size, kh, dh)), jnp.float32),
-              "v": jnp.asarray(rng.standard_normal(
-                  (n_pages, page_size, kh, dh)), jnp.float32)}
+        kv = pack_pages(*(jnp.asarray(rng.standard_normal(
+            (1, n_pages, page_size, kh, dh)), jnp.float32)
+            for _ in range(2)))
         bt = jnp.asarray(table, jnp.int32)
         qpos = jnp.asarray(positions, jnp.int32)
         q = jnp.asarray(rng.standard_normal((batch, 1, h, dh)), jnp.float32)
@@ -173,7 +173,7 @@ def paged_attention_bench(*, smoke: bool, iters: int = 5):
         for label, lens in (("full_walk", full_lens),
                             ("early_exit", true_lens)):
             fn = jax.jit(lambda lens=lens: kops.paged_attention(
-                q, kv, bt, qpos, lens, kv_splits=kv_splits))
+                q, kv, bt, qpos, lens, kv_heads=kh, kv_splits=kv_splits))
             fn().block_until_ready()
             t0 = time.perf_counter()
             for _ in range(iters):

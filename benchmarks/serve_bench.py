@@ -453,6 +453,7 @@ def bench_attn_data_path(cfg, *, page_size, slots, seq_len, iters):
     import jax.numpy as jnp
 
     from repro.kernels import ops as kops
+    from repro.kernels.paged_attention import pack_pages, unpack_pages
     from repro.serve.engine import pages as PG
     from repro.serve.engine.pages import pages_for
 
@@ -467,12 +468,8 @@ def bench_attn_data_path(cfg, *, page_size, slots, seq_len, iters):
     rng = np.random.default_rng(0)
     lengths, n_pages, table, positions = ragged_paged_batch(
         slots, seq_len, page_size)
-    pool = {
-        "k": jnp.asarray(rng.standard_normal(
-            (nl, n_pages, page_size, kh, dh)), jnp.float32),
-        "v": jnp.asarray(rng.standard_normal(
-            (nl, n_pages, page_size, kh, dh)), jnp.float32),
-    }
+    pool = pack_pages(*(jnp.asarray(rng.standard_normal(
+        (nl, n_pages, page_size, kh, dh)), jnp.float32) for _ in range(2)))
     bt = jnp.asarray(table, jnp.int32)
     qpos = jnp.asarray(positions, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
@@ -490,16 +487,13 @@ def bench_attn_data_path(cfg, *, page_size, slots, seq_len, iters):
 
     @jax.jit
     def slab_step(pool, q):
-        slab = PG.gather_pages(pool, bt)
-        return jnp.stack([slab_attn(q[l], slab["k"][l], slab["v"][l])
-                          for l in range(nl)])
+        k, v, _ = unpack_pages(PG.gather_pages(pool, bt, kv_heads=kh), kh)
+        return jnp.stack([slab_attn(q[l], k[l], v[l]) for l in range(nl)])
 
     @jax.jit
     def paged_step(pool, q):
         return jnp.stack([
-            kops.paged_attention(
-                q[l], {"k": pool["k"][l], "v": pool["v"][l]}, bt, qpos,
-                lens)
+            kops.paged_attention(q[l], pool, bt, qpos, lens, l, kv_heads=kh)
             for l in range(nl)])
 
     slab_bytes = 2 * nl * slots * per_seq * page_size * kh * dh * 4

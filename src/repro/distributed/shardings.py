@@ -182,6 +182,13 @@ def cache_spec(path: str, shape, mesh: Mesh) -> P:
         if mdl is not None and s % msize == 0:
             return P(None, dp, mdl, None, None)   # sequence-sharded cache
         return P(None, dp, None, None, None)
+    # the integer path's cache in the page format of
+    # `kernels.paged_attention`: position-major rows, so a block of rows
+    # (or of scale/zero lanes) is a block of positions — sequence-sharded
+    if name == "kv_rows":       # [L, B, S·KH, 2·Dh]
+        return P(None, dp, mdl, None)
+    if name == "kv_scale_zero":  # [L, B, 4, S·KH]
+        return P(None, dp, None, mdl)
     if name == "conv":          # [L, B, W, C]
         return P(None, dp, None, mdl)
     if name == "state":         # [L, B, H, N, P]

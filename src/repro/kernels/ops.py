@@ -161,38 +161,43 @@ def int4_matmul(act_codes, act_scale, act_zero, w_packed, w_scale,
 
 def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
                     q_positions: jnp.ndarray,
-                    seq_lengths: jnp.ndarray | None = None, *,
+                    seq_lengths: jnp.ndarray | None = None,
+                    layer: jnp.ndarray | int | None = None, *,
+                    kv_heads: int,
                     rope_theta: float | None = None,
                     kv_bits: int | None = None,
                     kv_group: int | None = None,
                     q_block: int | None = None,
                     kv_splits: int | None = None,
                     head_block: int | None = None) -> jnp.ndarray:
-    """Block-table-native causal attention over one layer's KV page pool.
+    """Block-table-native causal attention over layer `layer` of a KV
+    page pool.
 
-    q [B, S, H, Dh] (already rotated), kv pages [n_pages, T, KH, Dh]
-    (float post-RoPE K, or int8/int4 codes + scale/zero pages with
-    `kv_bits`/`kv_group` set — dequant and the pre-RoPE K rotation happen
-    inside the walk), block_tables [B, P] int32, q_positions [B, S],
-    seq_lengths [B] optional true context lengths — the ragged early-exit
-    skips every table column past ceil(len/page_size) (0 skips a padded
-    row's walk entirely). `q_block`/`kv_splits`/`head_block` tile the
-    flash-decoding grid (`resolve_tiling` defaults); both paths resolve
-    them identically, so the split/combine reduction order matches.
-    Pallas on TPU, interpret elsewhere, the bit-identical jnp page walk
-    under `use_kernels(False)`. Returns [B, S, H, Dh] f32.
+    q [B, S, H, Dh] (already rotated); kv the whole pool in the
+    tile-dense page format of `kernels.paged_attention` — "kv_rows"
+    [L, n_pages, T·KH, 2·Dh] (float post-RoPE K|V, or int8/int4 codes
+    with "kv_scale_zero" [L, n_pages, 4, T·KH] and `kv_bits`/`kv_group`
+    set: dequant and the pre-RoPE K rotation happen inside the walk) of
+    `kv_heads` KV heads; `layer` a traced scalar (None → 0) read by the
+    page index maps, so a layer loop can carry the pool and hand it over
+    whole; block_tables [B, P] int32, q_positions [B, S], seq_lengths [B]
+    optional true context lengths — the ragged early-exit skips every
+    table column past ceil(len/page_size) (0 skips a padded row's walk
+    entirely). `q_block`/`kv_splits`/`head_block` tile the flash-decoding
+    grid (`resolve_tiling` defaults); both paths resolve them
+    identically, so the split/combine reduction order matches. Pallas on
+    TPU, interpret elsewhere, the bit-identical jnp page walk under
+    `use_kernels(False)`. Returns [B, S, H, Dh] f32.
     """
     _record_dispatch("paged_attention")
+    kw = dict(kv_heads=kv_heads, rope_theta=rope_theta, kv_bits=kv_bits,
+              kv_group=kv_group, q_block=q_block, kv_splits=kv_splits,
+              head_block=head_block)
     if not kernels_enabled():
-        return _ref.paged_attention_ref(
-            q, kv, block_tables, q_positions, seq_lengths,
-            rope_theta=rope_theta, kv_bits=kv_bits, kv_group=kv_group,
-            q_block=q_block, kv_splits=kv_splits, head_block=head_block)
-    return _pa_kernel(q, kv, block_tables, q_positions, seq_lengths,
-                      rope_theta=rope_theta, kv_bits=kv_bits,
-                      kv_group=kv_group, q_block=q_block,
-                      kv_splits=kv_splits, head_block=head_block,
-                      interpret=not _on_tpu())
+        return _ref.paged_attention_ref(q, kv, block_tables, q_positions,
+                                        seq_lengths, layer, **kw)
+    return _pa_kernel(q, kv, block_tables, q_positions, seq_lengths, layer,
+                      interpret=not _on_tpu(), **kw)
 
 
 def infer_int4_scales(w: jnp.ndarray) -> jnp.ndarray:

@@ -25,19 +25,44 @@ slab — on the grid
     `Σ_b ceil(len_b / page_size)` — a real work reduction for ragged
     batches (exact by construction: a fully-masked page leaves `m/l/acc`
     bitwise unchanged, so skipping it is a bit-for-bit no-op).
-  * **Pipelined page fetch** — K/V pages (and the scale/zero pages of the
-    integer formats) ride BlockSpecs whose index map reads the
-    scalar-prefetched block table, so Pallas double-buffers them: the
+  * **Pipelined page fetch** — the kernel takes every layer's pool
+    (`[n_layers, n_pages, ...]` leaves) and the layer to read as a third
+    scalar-prefetch operand, so a model's layer loop can carry the pool
+    and hand it over whole: nothing slices a layer out of it. A page is
+    ONE block of K|V rows (plus one block of its scale/zero rows for the
+    integer formats) whose index map `(layer, block_table[b, col], 0, 0)`
+    reads the scalar-prefetched table, so Pallas double-buffers them: the
     copy of the next column overlaps the update of the current one. A
     column past the used count maps to the last live page again, and an
     unchanged block index issues no copy, so dead columns cost no DMA.
+
+**The page format** (`pack_pages` / `unpack_pages` / `zero_pages`, and
+`paged_write_rows`, which writes new positions in place). Each pool leaf's two
+minor dims are the page's rows and lanes, sized so they fill whole TPU
+tiles — (32, 128) for int8, (16, 128) for bf16, (8, 128) for f32 — and
+XLA stores the pool row-major, the layout the Pallas operand takes, with
+no relayout anywhere in a step:
+
+  * `kv_rows` `[..., n_pages, T·KH, 2·Dh]`: row `t·KH + j` holds position
+    t of KV head j, K in lanes `[0, Dh)` and V in `[Dh, 2·Dh)` — float
+    K/V, or int8 codes (int4 codes stored in int8) offset by 2^(bits-1).
+    A 16-row page of 8 heads of 64 is one (128, 128) int8 block: 16 KiB,
+    one DMA, no padding.
+  * `kv_scale_zero` `[..., n_pages, 4, T·KH]` f32 (integer formats only):
+    rows `k_scale, k_zero, v_scale, v_zero`, lane `t·KH + j` — one
+    asymmetric pair per (position, head), in the rows' order.
+
+The rule is tile-dense whenever 2·Dh is a multiple of 128 and T·KH one of
+the dtype's sublane tile, which every served head size (64, 128) meets at
+16-row pages with 2 or more KV heads (the scale/zero block of 4 rows
+fills half an f32 tile: 2 KiB of 18 KiB a page).
 
 Three KV page formats are served by the same walk:
 
   * float pages (bf16/f32) holding post-RoPE K — the bf16 and fake-quant
     engine backends;
   * int8/int4 code pages with per-(position, head) asymmetric
-    scale/zero pages riding along — dequantized in VMEM, and (because the
+    scale/zero rows riding along — dequantized in VMEM, and (because the
     integer cache stores K pre-RoPE) rotated in-kernel with the absolute
     position of each page row.
 
@@ -66,13 +91,104 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention", "paged_attention_reference", "resolve_tiling",
-           "used_page_counts", "rope_frequencies"]
+           "used_page_counts", "rope_frequencies", "pack_pages",
+           "unpack_pages", "zero_pages", "paged_write_rows", "ROWS",
+           "SCALE_ZERO", "AUX"]
 
 MASK_VALUE = -1e30
 # f32 dots at full precision on the chip too (XLA's and Mosaic's default
 # for an f32 matmul may drop to one bf16 pass), so the kernel and the
 # XLA reference compute the same arithmetic on every backend
 _F32 = jax.lax.Precision.HIGHEST
+
+# pool leaf names, and the row order of the scale/zero leaf
+ROWS, SCALE_ZERO = "kv_rows", "kv_scale_zero"
+AUX = ("k_scale", "k_zero", "v_scale", "v_zero")
+
+
+# ---------------------------------------------------------------------------
+# The page format
+# ---------------------------------------------------------------------------
+
+def pack_pages(k: jnp.ndarray, v: jnp.ndarray,
+               aux: tuple | None = None) -> dict:
+    """Logical pages → pool leaves (module docstring, "The page format").
+
+    k, v [..., T, KH, Dh]; aux = (k_scale, k_zero, v_scale, v_zero), each
+    [..., T, KH], for the integer formats. Returns {"kv_rows": [...,
+    T·KH, 2·Dh]} (+ "kv_scale_zero" [..., 4, T·KH]). With T = 1 it packs
+    single rows, the unit a page write scatters."""
+    *lead, t, kh, dh = k.shape
+    out = {ROWS: jnp.concatenate([k, v.astype(k.dtype)], axis=-1).reshape(
+        *lead, t * kh, 2 * dh)}
+    if aux is not None:
+        out[SCALE_ZERO] = jnp.stack(aux, axis=-3).astype(
+            jnp.float32).reshape(*lead, len(AUX), t * kh)
+    return out
+
+
+def unpack_pages(pool: dict, kv_heads: int):
+    """Pool leaves → logical pages: (k, v [..., T, KH, Dh], aux) with aux
+    the four [..., T, KH] scale/zero arrays of `AUX`, or None for float
+    pages. The inverse of `pack_pages`."""
+    rows = pool[ROWS]
+    *lead, c, w = rows.shape
+    t, dh = c // kv_heads, w // 2
+    kv = rows.reshape(*lead, t, kv_heads, w)
+    aux = None
+    if SCALE_ZERO in pool:
+        sz = pool[SCALE_ZERO].reshape(*lead, len(AUX), t, kv_heads)
+        aux = tuple(sz[..., i, :, :] for i in range(len(AUX)))
+    return kv[..., :dh], kv[..., dh:], aux
+
+
+def zero_pages(shape: tuple, dtype, *, scale_zero: bool = False) -> dict:
+    """Zeroed pool leaves for logical pages of `shape` [..., T, KH, Dh]:
+    "kv_rows" in `dtype`, and the f32 "kv_scale_zero" leaf of the integer
+    formats when `scale_zero`."""
+    *lead, t, kh, dh = shape
+    out = {ROWS: jnp.zeros((*lead, t * kh, 2 * dh), dtype)}
+    if scale_zero:
+        out[SCALE_ZERO] = jnp.zeros((*lead, len(AUX), t * kh), jnp.float32)
+    return out
+
+
+def paged_write_rows(pool: dict, rows: dict, block_table: jnp.ndarray,
+                     positions: jnp.ndarray) -> dict:
+    """Write new KV rows straight into their pages, in place.
+
+    pool: pool leaves in the page format ("kv_rows" [..., n_pages, T·KH,
+    2·Dh], and "kv_scale_zero" [..., n_pages, 4, T·KH] for integer
+    pages); rows: the same leaves for the new positions, [B, S, KH, 2·Dh]
+    and [B, S, 4, KH] (`pack_pages` of one position); block_table [B, P]
+    int32 page ids; positions [B, S] absolute positions. Position p of
+    row b lands in page block_table[b, p // T] at rows (p % T)·KH + j
+    (lanes, for the scale/zero leaf). Page ids index the leaves' leading
+    axes taken as one, so a layer's pages in an [L, n_pages, ...] pool
+    are `layer·n_pages + id` and the write lands at [layer, page, slot]
+    without slicing a layer out. One loop of in-place updates, one per
+    (b, s), writes every leaf. Padded rows target the scratch page (or
+    not-yet-valid in-page slots that are overwritten before the causal
+    mask ever exposes them), so duplicate writes are harmless.
+    """
+    flat = {k: a.reshape(-1, *a.shape[-2:]) for k, a in pool.items()}
+    kh = rows[ROWS].shape[-2]
+    t = flat[ROWS].shape[-2] // kh
+    pages = jnp.take_along_axis(block_table, positions // t,
+                                axis=1).reshape(-1).astype(jnp.int32)
+    starts = ((positions % t) * kh).reshape(-1).astype(jnp.int32)
+    new = {k: v.reshape(-1, 1, *v.shape[2:]).astype(pool[k].dtype)
+           for k, v in rows.items()}
+
+    def write(i, flat):
+        zero = jnp.zeros((), jnp.int32)
+        at = {ROWS: (pages[i], starts[i], zero),
+              SCALE_ZERO: (pages[i], zero, starts[i])}
+        return {k: jax.lax.dynamic_update_slice(a, new[k][i], at[k])
+                for k, a in flat.items()}
+
+    flat = jax.lax.fori_loop(0, pages.shape[0], write, flat)
+    return {k: flat[k].reshape(pool[k].shape) for k in pool}
 
 
 # ---------------------------------------------------------------------------
@@ -82,11 +198,11 @@ _F32 = jax.lax.Precision.HIGHEST
 # One grid instance owns a (q_block, head_block) tile. Its queries are laid
 # out as R = q_block · head_block · G rows (row r = s·hbg + j·G + g: query
 # position s, KV head j of the block, group member g) and one page of the
-# block's KV heads as C = page_size · head_block rows (row c = t·hb + j).
-# Every step is then a plain 2-D matmul: logits [R, C] = q · kᵀ with the
-# rows of other KV heads masked out, which keeps the page in the pool's
-# own [T, KH, Dh] layout (no in-kernel transpose) at the price of
-# head_block× redundant MXU work on the masked entries.
+# block's KV heads as C = page_size · head_block rows (row c = t·hb + j,
+# the pool's own row order). Every step is then a plain 2-D matmul:
+# logits [R, C] = q · kᵀ with the rows of other KV heads masked out, which
+# keeps the page in the pool's layout (no in-kernel transpose of K) at
+# the price of head_block× redundant MXU work on the masked entries.
 
 def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     """Twin of `models.layers.rope_frequencies` — duplicated on purpose,
@@ -104,17 +220,35 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return jnp.asarray(freqs, jnp.float32)
 
 
-def dequant_page(codes: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray,
+def dequant_rows(codes: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray,
                  *, bits: int) -> jnp.ndarray:
-    """Asymmetric per-(row, head) dequant of one KV page → [T·KH, Dh] f32.
+    """Asymmetric per-(position, head) dequant of page rows → [C, Dh] f32.
 
-    codes [T, KH, Dh] int8 (stored offset by 2^(bits-1)), scale/zero
-    [T, KH, 1] — the exact arithmetic of `QuantizedDenseLM._cache_read`.
+    codes [C, Dh] (int8 codes stored offset by 2^(bits-1), as f32),
+    scale/zero [C, 1] — the exact arithmetic of
+    `QuantizedDenseLM._cache_read`.
     """
     off = 2 ** (bits - 1)
-    t, kh, dh = codes.shape
-    c = codes.astype(jnp.float32).reshape(t * kh, dh) + off
-    return scale.reshape(t * kh, 1) * (c + zero.reshape(t * kh, 1))
+    return scale * ((codes + off) + zero)
+
+
+def head_rows(page: jnp.ndarray, sz: jnp.ndarray | None, h0, *, kv_heads: int,
+              head_block: int):
+    """The rows of KV heads [h0, h0 + head_block) of one page: page
+    [T·KH, W] → [T·hb, W], sz [4, T·KH] → [4, T·hb]. A tile of every head
+    takes the page as it is; a smaller head block (interpret mode only:
+    the TPU's DMA cannot cut a head out of a page's sublanes) slices it."""
+    if head_block == kv_heads:
+        return page, sz
+    t = page.shape[0] // kv_heads
+    page = jax.lax.dynamic_slice_in_dim(
+        page.reshape(t, kv_heads, -1), h0, head_block, axis=1)
+    page = page.reshape(t * head_block, -1)
+    if sz is not None:
+        sz = jax.lax.dynamic_slice_in_dim(
+            sz.reshape(len(AUX), t, kv_heads), h0, head_block, axis=2)
+        sz = sz.reshape(len(AUX), t * head_block)
+    return page, sz
 
 
 def rope_page(k: jnp.ndarray, kpos: jnp.ndarray,
@@ -152,23 +286,27 @@ def page_update(m, l, acc, q, k, v, valid, scale):
     return m_new, l_new, acc_new
 
 
-def walk_step(carry, q, qpos, col, k, v, aux, *, scale, bits, freqs, g):
+def walk_step(carry, q, qpos, col, page, sz, h0, *, scale, bits, freqs, g,
+              kv_heads, head_block):
     """Advance one tile's `(m, l, acc)` by table column `col`.
 
-    q [R, Dh] f32 and qpos [R, 1] int32 are the tile's query rows; k/v
-    [T, hb, Dh] one page of the tile's KV heads (float, or codes with
-    `aux` = (k_scale, k_zero, v_scale, v_zero) [T, hb, 1] pages); `freqs`
-    [1, Dh/2] rotates dequantized (pre-RoPE) K, None for float pages.
+    q [R, Dh] f32 and qpos [R, 1] int32 are the tile's query rows; page
+    [T·KH, 2·Dh] one page's K|V rows (float, or codes with `sz` its
+    [4, T·KH] scale/zero rows), of which the tile reads KV heads
+    [h0, h0 + head_block); `freqs` [1, Dh/2] rotates dequantized
+    (pre-RoPE) K, None for float pages.
     """
-    t, hb, dh = k.shape
-    c = t * hb
+    page, sz = head_rows(page, sz, h0, kv_heads=kv_heads,
+                         head_block=head_block)
+    hb = head_block
+    c, w = page.shape
+    t, dh = c // hb, w // 2
+    x = page.astype(jnp.float32)
+    k, v = x[:, :dh], x[:, dh:]
     if bits is not None:
-        ks, kz, vs, vz = aux
-        k = dequant_page(k, ks, kz, bits=bits)
-        v = dequant_page(v, vs, vz, bits=bits)
-    else:
-        k = k.astype(jnp.float32).reshape(c, dh)
-        v = v.astype(jnp.float32).reshape(c, dh)
+        cols = sz.T                                           # [C, 4]
+        k = dequant_rows(k, cols[:, 0:1], cols[:, 1:2], bits=bits)
+        v = dequant_rows(v, cols[:, 2:3], cols[:, 3:4], bits=bits)
     if freqs is not None:
         kpos = col * t + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) // hb
         k = rope_page(k, kpos, freqs)
@@ -294,14 +432,13 @@ def used_page_counts(q_positions: jnp.ndarray,
 # Kernels
 # ---------------------------------------------------------------------------
 
-_AUX = ("k_scale", "k_zero", "v_scale", "v_zero")
-
-
-def _kernel(bt_ref, used_ref, *refs, g, scale, bits, rope, ncp, splits):
+def _kernel(bt_ref, used_ref, layer_ref, *refs, g, scale, bits, rope, ncp,
+            splits, kv_heads, head_block):
+    del layer_ref    # read by the index maps only
     quant = bits is not None
-    q_ref, qpos_ref, k_ref, v_ref, *rest = refs
+    q_ref, qpos_ref, kv_ref, *rest = refs
     if quant:
-        ks_ref, kz_ref, vs_ref, vz_ref, *rest = rest
+        sz_ref, *rest = rest
     if rope:
         fr_ref, *rest = rest
     if splits == 1:
@@ -310,6 +447,7 @@ def _kernel(bt_ref, used_ref, *refs, g, scale, bits, rope, ncp, splits):
         mp_ref, lp_ref, ap_ref, m_ref, l_ref, acc_ref = rest
 
     b = pl.program_id(0)
+    h0 = pl.program_id(1) * head_block
     ks = pl.program_id(3)
     p = pl.program_id(4)
     col = ks * ncp + p
@@ -326,12 +464,12 @@ def _kernel(bt_ref, used_ref, *refs, g, scale, bits, rope, ncp, splits):
     def _update():
         q = q_ref[0].astype(jnp.float32).reshape(rows, dh)
         qpos = qpos_ref[0].reshape(rows, 1)
-        aux = ((ks_ref[0], kz_ref[0], vs_ref[0], vz_ref[0]) if quant
-               else None)
         m, l, acc = walk_step(
             (m_ref[...], l_ref[...], acc_ref[...]), q, qpos, col,
-            k_ref[0], v_ref[0], aux, scale=scale, bits=bits,
-            freqs=fr_ref[...] if rope else None, g=g)
+            kv_ref[0, 0], sz_ref[0, 0] if quant else None, h0,
+            scale=scale, bits=bits,
+            freqs=fr_ref[...] if rope else None, g=g, kv_heads=kv_heads,
+            head_block=head_block)
         m_ref[...] = m
         l_ref[...] = l
         acc_ref[...] = acc
@@ -363,12 +501,34 @@ def _query_rows(q_positions: jnp.ndarray, h: int) -> jnp.ndarray:
                             (b, s, h, 1))
 
 
+def _page_geometry(q, kv, kv_heads, kv_bits, kv_group):
+    """(page_size, group size) of a pool, after checking it against q."""
+    dh = q.shape[3]
+    c, w = kv[ROWS].shape[-2:]
+    if w != 2 * dh or c % kv_heads:
+        raise ValueError(f"pool rows [{c}, {w}] are not pages of "
+                         f"{kv_heads} KV heads of K|V rows of {dh}")
+    if kv_bits is not None and kv_group != dh:
+        raise ValueError(f"kv_group {kv_group} != head_dim {dh}: pages "
+                         "carry one scale/zero pair per (position, head)")
+    return c // kv_heads, q.shape[2] // kv_heads
+
+
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as the kernel's [1] int32 scalar-prefetch operand
+    (None reads layer 0: a pool with one layer)."""
+    return jnp.reshape(jnp.asarray(0 if layer is None else layer,
+                                   jnp.int32), (1,))
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "rope_theta", "kv_bits", "kv_group", "q_block", "kv_splits",
-    "head_block", "interpret"))
+    "kv_heads", "rope_theta", "kv_bits", "kv_group", "q_block",
+    "kv_splits", "head_block", "interpret"))
 def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
                     q_positions: jnp.ndarray,
-                    seq_lengths: jnp.ndarray | None = None, *,
+                    seq_lengths: jnp.ndarray | None = None,
+                    layer: jnp.ndarray | int | None = None, *,
+                    kv_heads: int,
                     rope_theta: float | None = None,
                     kv_bits: int | None = None,
                     kv_group: int | None = None,
@@ -376,12 +536,14 @@ def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
                     kv_splits: int | None = None,
                     head_block: int | None = None,
                     interpret: bool = True) -> jnp.ndarray:
-    """Causal attention of `q` against one layer's KV page pool.
+    """Causal attention of `q` against layer `layer` of a KV page pool.
 
-    q [B, S, H, Dh] (queries already rotated); kv {"k", "v"} pages
-    [n_pages, T, KH, Dh] (+ "{k,v}_{scale,zero}" [n_pages, T, KH, 1]
-    when `kv_bits` is set — one scale/zero pair per (position, head), so
-    `kv_group` must equal Dh); block_tables [B, P] int32 (pad = scratch);
+    q [B, S, H, Dh] (queries already rotated); kv the pool in the page
+    format of the module docstring: "kv_rows" [L, n_pages, T·KH, 2·Dh]
+    (+ "kv_scale_zero" [L, n_pages, 4, T·KH] when `kv_bits` is set — one
+    scale/zero pair per (position, head), so `kv_group` must equal Dh),
+    of `kv_heads` KV heads; `layer` a traced scalar (None → 0) that the
+    page index maps read; block_tables [B, P] int32 (pad = scratch);
     q_positions [B, S] int32 absolute positions; seq_lengths [B] optional
     true context lengths (pages past ceil(len/T) are skipped — 0 skips the
     row's whole walk). `rope_theta` rotates the dequantized K pages
@@ -390,13 +552,10 @@ def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
     Returns [B, S, H, Dh] float32.
     """
     b, s, h, dh = q.shape
-    t, kh = kv["k"].shape[1], kv["k"].shape[2]
-    g = h // kh
+    kh = kv_heads
+    t, g = _page_geometry(q, kv, kh, kv_bits, kv_group)
     n_cols = block_tables.shape[1]
     quant = kv_bits is not None
-    if quant and kv_group != dh:
-        raise ValueError(f"kv_group {kv_group} != head_dim {dh}: pages "
-                         "carry one scale/zero pair per (position, head)")
     q_block, kv_splits, head_block, ncp = resolve_tiling(
         s, kh, n_cols, q_block, kv_splits, head_block)
     n_hb, n_qb = kh // head_block, s // q_block
@@ -415,38 +574,34 @@ def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
     rope = quant and rope_theta is not None
     kern = functools.partial(
         _kernel, g=g, scale=1.0 / math.sqrt(dh), bits=kv_bits, rope=rope,
-        ncp=ncp, splits=kv_splits)
+        ncp=ncp, splits=kv_splits, kv_heads=kh, head_block=head_block)
 
-    def page_spec(last):
+    def page_spec(leaf):
         # Pallas double-buffers these fetches. Columns past the used count
         # re-name the last live page, so the pipeline issues no copy for
         # them (the block index does not change) and the walk skips them.
-        def index(bb, hh, qq, ss, pp, bt, u):
+        def index(bb, hh, qq, ss, pp, bt, u, lyr):
             live = jnp.minimum(ss * ncp + pp, jnp.maximum(u[bb] - 1, 0))
-            return (bt[bb, live], 0, hh, 0)
-        return pl.BlockSpec((1, t, head_block, last), index)
+            return (lyr[0], bt[bb, live], 0, 0)
+        return pl.BlockSpec((1, 1) + leaf.shape[-2:], index)
 
-    in_specs = [
-        pl.BlockSpec((1, q_block, hbg, dh),
-                     lambda bb, hh, qq, ss, pp, bt, u: (bb, qq, hh, 0)),
-        pl.BlockSpec((1, q_block, hbg, 1),
-                     lambda bb, hh, qq, ss, pp, bt, u: (bb, qq, hh, 0)),
-        page_spec(dh), page_spec(dh),
-    ]
-    operands = [q, _query_rows(q_positions, h), kv["k"], kv["v"]]
+    def tile_map(bb, hh, qq, *_):
+        return (bb, qq, hh, 0)
+
+    in_specs = [pl.BlockSpec((1, q_block, hbg, dh), tile_map),
+                pl.BlockSpec((1, q_block, hbg, 1), tile_map),
+                page_spec(kv[ROWS])]
+    operands = [q, _query_rows(q_positions, h), kv[ROWS]]
     if quant:
-        in_specs += [page_spec(1)] * 4
-        operands += [kv[name] for name in _AUX]
+        in_specs.append(page_spec(kv[SCALE_ZERO]))
+        operands.append(kv[SCALE_ZERO])
     if rope:
-        in_specs.append(pl.BlockSpec(
-            (1, dh // 2), lambda bb, hh, qq, ss, pp, bt, u: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, dh // 2), lambda *_: (0, 0)))
         operands.append(rope_frequencies(dh, rope_theta)[None])
 
     if kv_splits == 1:
         out_shape = jax.ShapeDtypeStruct((b, s, h, dh), jnp.float32)
-        out_specs = pl.BlockSpec(
-            (1, q_block, hbg, dh),
-            lambda bb, hh, qq, ss, pp, bt, u: (bb, qq, hh, 0))
+        out_specs = pl.BlockSpec((1, q_block, hbg, dh), tile_map)
     else:
         out_shape = (
             jax.ShapeDtypeStruct((b, kv_splits, s, h, 1), jnp.float32),
@@ -456,12 +611,12 @@ def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
 
         def part_spec(last):
             return pl.BlockSpec((1, 1, q_block, hbg, last),
-                                lambda bb, hh, qq, ss, pp, bt, u:
+                                lambda bb, hh, qq, ss, *_:
                                 (bb, ss, qq, hh, 0))
         out_specs = (part_spec(1), part_spec(1), part_spec(dh))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_hb, n_qb, kv_splits, ncp),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -479,7 +634,8 @@ def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), used, *operands)
+    )(block_tables.astype(jnp.int32), used, _layer_operand(layer),
+      *operands)
     if kv_splits == 1:
         return result
 
@@ -504,12 +660,14 @@ def paged_attention(q: jnp.ndarray, kv: dict, block_tables: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=(
-    "rope_theta", "kv_bits", "kv_group", "q_block", "kv_splits",
-    "head_block"))
+    "kv_heads", "rope_theta", "kv_bits", "kv_group", "q_block",
+    "kv_splits", "head_block"))
 def paged_attention_reference(q: jnp.ndarray, kv: dict,
                               block_tables: jnp.ndarray,
                               q_positions: jnp.ndarray,
-                              seq_lengths: jnp.ndarray | None = None, *,
+                              seq_lengths: jnp.ndarray | None = None,
+                              layer: jnp.ndarray | int | None = None, *,
+                              kv_heads: int,
                               rope_theta: float | None = None,
                               kv_bits: int | None = None,
                               kv_group: int | None = None,
@@ -529,13 +687,10 @@ def paged_attention_reference(q: jnp.ndarray, kv: dict,
     rounds a ulp away from the compiled one).
     """
     b, s, h, dh = q.shape
-    t, kh = kv["k"].shape[1], kv["k"].shape[2]
-    g = h // kh
+    kh = kv_heads
+    t, g = _page_geometry(q, kv, kh, kv_bits, kv_group)
     n_cols = block_tables.shape[1]
     quant = kv_bits is not None
-    if quant and kv_group != dh:
-        raise ValueError(f"kv_group {kv_group} != head_dim {dh}: pages "
-                         "carry one scale/zero pair per (position, head)")
     scale = 1.0 / math.sqrt(dh)
     q_block, kv_splits, head_block, ncp = resolve_tiling(
         s, kh, n_cols, q_block, kv_splits, head_block)
@@ -547,21 +702,20 @@ def paged_attention_reference(q: jnp.ndarray, kv: dict,
     used = used_page_counts(q_positions, seq_lengths, t, n_cols)
     freqs = (rope_frequencies(dh, rope_theta)[None]
              if quant and rope_theta is not None else None)
+    lyr = _layer_operand(layer)[0]
 
     def one_sequence(qb, qposb, btb, used_b):
         def one_tile(q_tile, qpos_tile, h0):
             # q_tile [q_block, hbg, dh] — one grid instance
             q2 = q_tile.astype(jnp.float32).reshape(rows, dh)
             qp = qpos_tile.reshape(rows, 1)
-            hsl = slice(h0, h0 + head_block)
 
             def step(carry, inp):
                 col, page = inp
-                aux = (tuple(kv[name][page][:, hsl] for name in _AUX)
-                       if quant else None)
-                new = walk_step(carry, q2, qp, col, kv["k"][page][:, hsl],
-                                kv["v"][page][:, hsl], aux, scale=scale,
-                                bits=kv_bits, freqs=freqs, g=g)
+                sz = kv[SCALE_ZERO][lyr, page] if quant else None
+                new = walk_step(carry, q2, qp, col, kv[ROWS][lyr, page], sz,
+                                h0, scale=scale, bits=kv_bits, freqs=freqs,
+                                g=g, kv_heads=kh, head_block=head_block)
                 # the kernel skips dead columns outright; selecting the
                 # old carry replays that skip exactly
                 keep = col < used_b

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.distributed.context import mesh_axis_size, shard_act
 from repro.kernels import ops as kops
+from repro.kernels.paged_attention import pack_pages, paged_write_rows
 
 Params = dict[str, Any]
 
@@ -192,23 +193,6 @@ def _chunked_attention(q, k, v, *, causal, chunk_q, chunk_kv, q_offset=0):
     return out.astype(q.dtype)
 
 
-def paged_write_rows(buf: jnp.ndarray, val: jnp.ndarray,
-                     block_table: jnp.ndarray,
-                     positions: jnp.ndarray) -> jnp.ndarray:
-    """Scatter new KV rows straight into their pages.
-
-    buf [n_pages, T, ...] pool leaf; val [B, S, ...]; block_table [B, P]
-    int32; positions [B, S] absolute positions. Row (b, s) lands at
-    (block_table[b, positions//T], positions % T). Padded rows target the
-    scratch page (or not-yet-valid in-page slots that are overwritten
-    before the causal mask ever exposes them), so duplicate writes are
-    harmless.
-    """
-    t = buf.shape[1]
-    page_ids = jnp.take_along_axis(block_table, positions // t, axis=1)
-    return buf.at[page_ids, positions % t].set(val.astype(buf.dtype))
-
-
 def attention(x: jnp.ndarray, p: Params, spec: AttnSpec, *,
               positions: jnp.ndarray | None = None,
               cache: Params | None = None,
@@ -220,8 +204,9 @@ def attention(x: jnp.ndarray, p: Params, spec: AttnSpec, *,
 
     cache = {"k": [B, S_max, KH, Dh], "v": ...} for decode; `cache_index`
     is the current fill position (scalar int32, or [B] per-slot vector).
-    With `block_table` [B, P] the cache is instead a *paged view* — leaves
-    [n_pages, page_size, KH, Dh] — and attention is block-table-native:
+    With `block_table` [B, P] the cache is instead one layer's page pool
+    ({"kv_rows": [n_pages, T·KH, 2·Dh]}, the page format of
+    `kernels.paged_attention`) and attention is block-table-native:
     the new rows are scattered straight into their pages and the kernel
     walks the table (`kernels.ops.paged_attention`), no gathered slab.
     `seq_lengths` [B] (paged path only) are the true per-sequence context
@@ -266,12 +251,12 @@ def attention(x: jnp.ndarray, p: Params, spec: AttnSpec, *,
         # block-table-native path: write the new rows straight into their
         # pages, then attend by walking the table — no gathered slab. K is
         # stored post-RoPE, so the kernel applies no rotation.
-        new_cache = {
-            "k": paged_write_rows(cache["k"], k, block_table, positions),
-            "v": paged_write_rows(cache["v"], v, block_table, positions),
-        }
-        out = kops.paged_attention(q, new_cache, block_table, positions,
-                                   seq_lengths).astype(x.dtype)
+        new_cache = paged_write_rows(
+            cache, pack_pages(k[:, :, None], v[:, :, None]), block_table,
+            positions)
+        out = kops.paged_attention(
+            q, jax.tree.map(lambda a: a[None], new_cache), block_table,
+            positions, seq_lengths, kv_heads=kv).astype(x.dtype)
     elif cache is not None:
         if per_slot:
             if s != 1:

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.context import shard_act
+from repro.kernels.paged_attention import zero_pages
 from . import layers as L
 from . import moe as M
 from . import ssm as S
@@ -395,9 +396,10 @@ class Model:
                          dtype=jnp.bfloat16) -> Params:
         """Engine-owned partitioned state `{"kv": ..., "register": ...}`.
 
-        kv leaves are page pools ([n_layers/n_groups, n_pages, page_size,
-        ...], block-table-indexed); register leaves are slot pools
-        ([n_layers, n_slots, ...], one fixed slot per admitted sequence).
+        kv leaves are page pools ([n_layers/n_groups, n_pages, ...] in the
+        page format of `kernels.paged_attention`, block-table-indexed);
+        register leaves are slot pools ([n_layers, n_slots, ...], one
+        fixed slot per admitted sequence).
         Dense/MoE state is pure kv, pure SSM is pure register, hybrid
         mixes both kinds.
         """
@@ -413,15 +415,17 @@ class Model:
                 head_dim=cfg.ssm_head_dim, state=cfg.ssm_state,
                 conv_width=cfg.ssm_conv_width, dtype=dtype), cfg.n_layers)
 
+        def pages():
+            return zero_pages((n_pages, page_size, cfg.n_kv_heads,
+                               cfg.head_dim), dtype)
+
         if cfg.family == "ssm":
             return {"kv": {}, "register": ssm_slots()}
         if cfg.family == "hybrid":
             n_groups, _, _ = self._hybrid_groups()
-            shared = stack(L.init_attention_cache(
-                n_pages, page_size, self.attn_spec, dtype), n_groups)
-            return {"kv": {"shared": shared}, "register": {"ssm": ssm_slots()}}
-        return {"kv": self.init_cache(n_pages, page_size, dtype),
-                "register": {}}
+            return {"kv": {"shared": stack(pages(), n_groups)},
+                    "register": {"ssm": ssm_slots()}}
+        return {"kv": stack(pages(), cfg.n_layers), "register": {}}
 
     def prefill(self, params: Params, batch: Params, caches: Params):
         """Process the prompt, fill caches, return last-position logits."""
@@ -445,7 +449,7 @@ class Model:
         index is one chunk of an incremental (chunked) prefill, causal
         within the chunk and attending to everything already cached. With
         `block_table` [B, P], kv leaves of `caches` are the engine's page
-        pool ([n_layers, n_pages, page_size, ...]) and attention runs
+        pool (`init_paged_state`) and attention runs
         block-table-native — new rows are written straight into their
         pages and the paged-attention kernel walks the table;
         `seq_lengths` [B] (the true per-sequence context lengths, 0 for

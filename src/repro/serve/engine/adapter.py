@@ -123,8 +123,9 @@ class ServableModel(Protocol):
     def init_state(self, n_pages: int, page_size: int,
                    n_slots: int) -> Params:
         """Partitioned `{"kv": ..., "register": ...}` paged state: kv
-        leaves [n_layers, n_pages, page_size, ...], register leaves
-        [n_layers, n_slots, ...]. Either part may be empty per the spec."""
+        leaves [n_layers, n_pages, ...] in the page format of
+        `kernels.paged_attention`, register leaves [n_layers, n_slots,
+        ...]. Either part may be empty per the spec."""
         ...
 
     def init_cache(self, batch: int, max_len: int) -> Params:

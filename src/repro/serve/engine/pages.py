@@ -5,7 +5,8 @@ The engine's device-side state is one partitioned pytree per served model,
 kinds of per-sequence state:
 
   * **kv** leaves grow with sequence length. They live in a single *page
-    pool* per leaf — shape `[n_layers, n_pages, page_size, ...]` — with a
+    pool* per leaf — shape `[n_layers, n_pages, ...]`, each page in the
+    tile-dense page format of `kernels.paged_attention` — with a
     host-side block table per sequence mapping logical positions to pages.
     Pages are allocated lazily as a sequence grows and freed on completion,
     so pool HBM is shared across sequences of very different lengths (the
@@ -29,10 +30,12 @@ contiguous slab is ever materialised. Register leaves are gathered by slot
 index at the top of the forward and scattered back once per call.
 
 Both pools are format-agnostic: they are built by the adapter's
-`init_state(n_pages, page_size, n_slots)` — the page/slot axis *is* the
-batch axis — so the same machinery pages the bf16 cache ({k, v}), the
-asymmetric per-(position, head) int8/int4 KV cache (codes *and* their
-scale/zero rows), and the SSM conv/SSD slot pools.
+`init_state(n_pages, page_size, n_slots)` — the page/slot axis is axis 1
+of every leaf, after the layer axis — so the same machinery (scrub,
+copy-on-write, swap, the host mirror) pages the float cache ({kv_rows}),
+the asymmetric per-(position, head) int8/int4 KV cache (codes *and*
+their scale/zero rows, {kv_rows, kv_scale_zero}), and the SSM conv/SSD
+slot pools.
 
 This module keeps the *bookkeeping*: the two allocators, block tables and
 register-slot maps, and release-time scrubbing. KV pages are
@@ -74,8 +77,7 @@ simply retry. Each release scrubs through ONE fused jit dispatch (pages
 of every kv leaf + the register slot together, page counts padded to
 powers of two to bound the jit variants), tallied as `scrub_state` in
 the `kernels.ops` dispatch counts. The legacy `gather_pages` /
-`scatter_*_rows` primitives survive purely as the test oracle the paged
-kernel is checked against.
+`scatter_decode_rows` primitives survive purely as test oracles.
 
 **Two-tier residency.** When a `HostSwapPool` is attached (an
 engine-configured host-memory budget, `--swap-host-mb`), a KV page has
@@ -115,6 +117,7 @@ also what makes scratch-padded scrub index vectors harmless).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -122,6 +125,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kops
+from repro.kernels.paged_attention import pack_pages, unpack_pages
 
 Params = dict[str, Any]
 
@@ -204,9 +208,9 @@ class HostSwapPool:
     """Host-memory mirror of the device kv page pool — the swap tier.
 
     One numpy buffer per kv leaf, shaped like the device pool with the
-    page axis resized to the budget: `[n_layers, n_slots, page_size,
-    ...]`. Capacity is derived from a byte budget and the per-page byte
-    cost of the adapter's state spec (quantized page formats shrink it
+    page axis resized to the budget: `[n_layers, n_slots, ...]`.
+    Capacity is derived from a byte budget and the per-page byte cost of
+    the adapter's state spec (quantized page formats shrink it
     4-8x, which is what makes offload cheaper than recompute). Slots are
     a plain free list — host pages are never shared (only exclusively
     held device pages are ever swapped out), so there is no refcounting
@@ -414,56 +418,51 @@ class RegisterAllocator:
         self._free.append(slot)
 
 
-@jax.jit
-def gather_pages(pool: Params, block_tables: jnp.ndarray) -> Params:
+@functools.partial(jax.jit, static_argnames="kv_heads")
+def gather_pages(pool: Params, block_tables: jnp.ndarray, *,
+                 kv_heads: int) -> Params:
     """Gather pages into contiguous per-sequence slabs (TEST ORACLE ONLY —
     the serving path is block-table-native and never materialises slabs).
 
-    pool leaves: [n_layers, n_pages, page_size, ...]
+    pool leaves: [n_layers, n_pages, ...] in the page format of
+    `kernels.paged_attention`, with `kv_heads` KV heads
     block_tables: [B, P] int32 page ids (pad entries = SCRATCH_PAGE)
-    returns leaves: [n_layers, B, P·page_size, ...]
+    returns the same format with one page of P·page_size positions per
+    sequence ([n_layers, B, ...]: a dense cache `unpack_pages` reads).
     """
     b, p = block_tables.shape
 
-    def g(leaf):
-        s = jnp.take(leaf, block_tables.reshape(-1), axis=1)
-        return s.reshape(leaf.shape[0], b, p * leaf.shape[2], *leaf.shape[3:])
+    def g(a):
+        s = jnp.take(a, block_tables.reshape(-1), axis=1)
+        return s.reshape(a.shape[0], b, p * a.shape[2], *a.shape[3:])
 
-    return jax.tree.map(g, pool)
+    k, v, aux = unpack_pages(pool, kv_heads)
+    return pack_pages(g(k), g(v), None if aux is None else tuple(map(g, aux)))
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames="kv_heads")
 def scatter_decode_rows(pool: Params, slab: Params, fill_pos: jnp.ndarray,
-                        page_ids: jnp.ndarray, offsets: jnp.ndarray) -> Params:
+                        page_ids: jnp.ndarray, offsets: jnp.ndarray, *,
+                        kv_heads: int) -> Params:
     """Write each slot's newly decoded KV row back into its page (TEST
     ORACLE ONLY — the forward writes rows in place on the serving path).
 
-    Extracts row `fill_pos[i]` of slot i from every slab leaf and stores it
-    at (page_ids[i], offsets[i]) in the pool. Padded slots point at the
-    scratch page, so their (duplicate) writes are harmless.
+    Extracts position `fill_pos[i]` of slot i from the slab (the format
+    `gather_pages` returns) and stores it at (page_ids[i], offsets[i]) in
+    the pool. Padded slots point at the scratch page, so their
+    (duplicate) writes are harmless.
     """
     rows = jnp.arange(fill_pos.shape[0])
+    k, v, aux = unpack_pages(slab, kv_heads)
+    pk, pv, paux = unpack_pages(pool, kv_heads)
 
-    def upd(p, s):
-        new = s[:, rows, fill_pos]                 # [n_layers, B, ...]
-        return p.at[:, page_ids, offsets].set(new.astype(p.dtype))
+    def put(dst, src):
+        return dst.at[:, page_ids, offsets].set(
+            src[:, rows, fill_pos].astype(dst.dtype))
 
-    return jax.tree.map(upd, pool, slab)
-
-
-@jax.jit
-def scatter_prefill_rows(pool: Params, slab: Params, positions: jnp.ndarray,
-                         page_ids: jnp.ndarray,
-                         offsets: jnp.ndarray) -> Params:
-    """Write a prefill chunk's KV rows (single sequence, slab batch row 0)
-    back into its pages: slab positions `positions[j]` land at
-    (page_ids[j], offsets[j]). TEST ORACLE ONLY — see gather_pages."""
-
-    def upd(p, s):
-        new = s[:, 0, positions]                   # [n_layers, S, ...]
-        return p.at[:, page_ids, offsets].set(new.astype(p.dtype))
-
-    return jax.tree.map(upd, pool, slab)
+    return pack_pages(put(pk, k), put(pv, v),
+                      None if aux is None
+                      else tuple(put(d, s) for d, s in zip(paux, aux)))
 
 
 class PagedKVCache:

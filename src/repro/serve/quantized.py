@@ -25,12 +25,16 @@ cached pre-RoPE (the rotation is re-applied after dequant at read time —
 RoPE mixes each outlier channel across a position-dependent pair of
 channels, which inflates the quantization range and wastes code points).
 
-Under the paged serving engine `forward_chunk` additionally takes the
-per-sequence block tables: new KV rows (codes + scale/zero for integer
-caches) are scattered straight into their pool pages and attention walks
-the table through `ops.paged_attention`, which dequantizes and re-rotates
-K inside the kernel — the same arithmetic as the dense read path, minus
-the slab.
+The cache is a page pool in the tile-dense page format of
+`kernels.paged_attention` (a dense cache is the same pool with one page
+per batch row). The layer loop carries it: each layer scatters its new
+KV rows (codes + scale/zero for integer caches) in place at [layer,
+page, slot]. Under the paged serving engine `forward_chunk` additionally
+takes the per-sequence block tables and attention walks them through
+`ops.paged_attention`, which reads pages by (layer, page), dequantizes
+and re-rotates K inside the kernel — the same arithmetic as the dense
+read path, minus the slab — so a step neither slices nor copies the
+pool.
 
 `forward_chunk(..., probe=True)` compiles a probe variant that
 additionally returns per-layer rotation-quality stats from the fused
@@ -51,6 +55,8 @@ import jax.numpy as jnp
 
 from repro.distributed.context import shard_act
 from repro.kernels import ops as kops
+from repro.kernels.paged_attention import (ROWS, pack_pages, unpack_pages,
+                                           zero_pages)
 from repro.models import layers as L
 from repro.models.config import ArchConfig
 from repro.serve.telemetry.quality import activation_probe_stats
@@ -147,103 +153,70 @@ class QuantizedDenseLM:
         self._jit_cache: dict = {}
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
-        if self.kv_bits is not None:
-            kv, dh = self.cfg.n_kv_heads, self.cfg.head_dim
-            ng = dh // self.kv_group
-            one = {
-                "k": jnp.zeros((batch, max_len, kv, dh), jnp.int8),
-                "v": jnp.zeros((batch, max_len, kv, dh), jnp.int8),
-                "k_scale": jnp.ones((batch, max_len, kv, ng), jnp.float32),
-                "v_scale": jnp.ones((batch, max_len, kv, ng), jnp.float32),
-                "k_zero": jnp.zeros((batch, max_len, kv, ng), jnp.float32),
-                "v_zero": jnp.zeros((batch, max_len, kv, ng), jnp.float32),
-            }
-        else:
-            one = L.init_attention_cache(batch, max_len, self.attn_spec,
-                                         dtype)
-        return jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (self.cfg.n_layers, *a.shape)), one)
-
-    @staticmethod
-    def _write_rows(buf, val, index):
-        """Update `buf` [B, L, ...] with `val` [B, S, ...] at fill position
-        `index` — a scalar (all rows at the same offset, any S) or a [B]
-        vector (per-slot offsets, S == 1: the continuous-batching decode
-        case, mirroring the per-slot path in `models.layers.attention`)."""
-        if jnp.ndim(index) == 1:
-            rows = jnp.arange(buf.shape[0])
-            return buf.at[rows, index].set(val[:, 0].astype(buf.dtype))
-        return jax.lax.dynamic_update_slice(
-            buf, val.astype(buf.dtype), (0, index, 0, 0))
+        """The KV cache as a page pool in the tile-dense page format of
+        `kernels.paged_attention`, stacked over layers: "kv_rows"
+        [n_layers, batch, max_len·KH, 2·Dh] (int8 codes with `kv_bits`,
+        else `dtype` K|V rows) plus, for integer caches, "kv_scale_zero"
+        [n_layers, batch, 4, max_len·KH] f32. The engine builds its pool
+        as `init_cache(n_pages, page_size)`; a dense cache is the same
+        pool with one `max_len`-row page per batch row (`_forward`
+        addresses it through the identity block table)."""
+        quant = self.kv_bits is not None
+        return zero_pages((self.cfg.n_layers, batch, max_len,
+                           self.cfg.n_kv_heads, self.cfg.head_dim),
+                          jnp.int8 if quant else dtype, scale_zero=quant)
 
     def _quantize_kv(self, x):
-        """Asymmetric per-(position, head) KV quantization → (codes int8,
-        scale f32, zero f32). Codes are stored offset by 2^(bits-1) so the
-        unsigned range fits the int8 cache buffer at kv_bits=8."""
+        """Asymmetric per-(position, head) KV quantization of x [..., Dh]
+        → (codes int8 [..., Dh], scale f32 [...], zero f32 [...]). Codes
+        are stored offset by 2^(bits-1) so the unsigned range fits the
+        int8 cache buffer at kv_bits=8."""
         bits = self.kv_bits
         levels = 2 ** bits - 1
         off = 2 ** (bits - 1)
-        g = self.kv_group
-        shp = x.shape
-        xg = x.astype(jnp.float32).reshape(*shp[:-1], shp[-1] // g, g)
-        mn = jnp.min(xg, -1, keepdims=True)
-        mx = jnp.max(xg, -1, keepdims=True)
-        # floor keeps zero-range groups from dividing by 0 while leaving
+        xf = x.astype(jnp.float32)
+        mn = jnp.min(xf, -1, keepdims=True)
+        mx = jnp.max(xf, -1, keepdims=True)
+        # floor keeps zero-range heads from dividing by 0 while leaving
         # the zero-point small enough for exact f32 arithmetic
         s = jnp.maximum((mx - mn) / levels, 1e-6)
         z = jnp.round(mn / s)
-        codes = jnp.clip(jnp.round(xg / s) - z, 0, levels)
-        return ((codes - off).reshape(shp).astype(jnp.int8),
-                s[..., 0].astype(jnp.float32),
-                z[..., 0].astype(jnp.float32))
+        codes = jnp.clip(jnp.round(xf / s) - z, 0, levels)
+        return (codes - off).astype(jnp.int8), s[..., 0], z[..., 0]
 
-    def _kv_leaves(self, k, v):
-        """The (leaf name, value) pairs one KV write must store."""
+    def _paged_cache_write(self, cache, k, v, layer, positions,
+                           block_table):
+        """Scatter new rows k/v [B, S, KH, Dh] (integer caches: quantized
+        here, K pre-RoPE) straight into their pages of layer `layer`:
+        row (b, s) lands in place at [layer, block_table[b, pos // T],
+        pos % T] of every pool leaf, so a layer loop that carries the pool
+        never copies it."""
+        ids = block_table + layer * cache[ROWS].shape[1]   # [layer, page]
         if self.kv_bits is None:
-            return (("k", k), ("v", v))
-        kq, ks, kz = self._quantize_kv(k)
-        vq, vs, vz = self._quantize_kv(v)
-        return (("k", kq), ("v", vq), ("k_scale", ks), ("v_scale", vs),
-                ("k_zero", kz), ("v_zero", vz))
-
-    def _cache_write(self, cache, k, v, index):
-        """Write new K/V rows at positions [index, index+S) (bf16, or
-        asymmetric integer codes per kv_bits with per-(position, head)
-        scale+zero). For integer caches K arrives and is stored PRE-RoPE
-        (the rotation is applied after dequantization in `_block`); the
-        bf16 cache stores K already rotated."""
-        out = dict(cache)
-        for name, val in self._kv_leaves(k, v):
-            out[name] = self._write_rows(cache[name], val, index)
-        return out
-
-    def _paged_cache_write(self, cache, k, v, positions, block_table):
-        """Scatter new rows straight into their pages (pool leaves
-        [n_pages, page_size, ...]) — the block-table-native counterpart of
-        `_cache_write`, same quantization arithmetic."""
-        out = dict(cache)
-        for name, val in self._kv_leaves(k, v):
-            out[name] = L.paged_write_rows(cache[name], val, block_table,
-                                           positions)
-        return out
+            new = pack_pages(k[:, :, None], v[:, :, None])
+        else:
+            kq, ks, kz = self._quantize_kv(k)
+            vq, vs, vz = self._quantize_kv(v)
+            new = pack_pages(kq[:, :, None], vq[:, :, None],
+                             tuple(a[:, :, None] for a in (ks, kz, vs, vz)))
+        return L.paged_write_rows(cache, new, ids, positions)
 
     def _cache_read(self, cache):
-        """Dequantize the whole cache → (K, V); K is still pre-RoPE."""
+        """Dequantize a dense cache (pages of `max_len` rows, any leading
+        axes) → (K, V) [..., B, max_len, KH, Dh]; K is still pre-RoPE for
+        integer caches."""
+        k, v, aux = unpack_pages(cache, self.cfg.n_kv_heads)
         if self.kv_bits is None:
-            return cache["k"], cache["v"]
+            return k, v
         off = 2 ** (self.kv_bits - 1)
-        g = self.kv_group
+        ks, kz, vs, vz = (a[..., None] for a in aux)
 
         def dq(codes, scale, zero):
-            shp = codes.shape
-            cg = (codes.astype(jnp.float32) + off).reshape(
-                *shp[:-1], shp[-1] // g, g)
-            return (scale[..., None] * (cg + zero[..., None])).reshape(shp)
+            return scale * (codes.astype(jnp.float32) + off + zero)
 
-        return dq(cache["k"], cache["k_scale"], cache["k_zero"]), \
-            dq(cache["v"], cache["v_scale"], cache["v_zero"])
+        return dq(k, ks, kz), dq(v, vs, vz)
 
-    def _block(self, x, blk, cache, index, block_table=None,
+    def _block(self, x, blk, cache, layer, index, block_table=None,
                seq_lengths=None, probe=False):
         cfg = self.cfg
         spec = self.attn_spec
@@ -276,17 +249,23 @@ class QuantizedDenseLM:
             # block-table-native: scatter the new rows into their pages and
             # walk the table in the kernel (in-kernel dequant + pre-RoPE K
             # rotation for the integer page formats)
-            new_cache = self._paged_cache_write(cache, k, v, pos, block_table)
+            new_cache = self._paged_cache_write(cache, k, v, layer, pos,
+                                                block_table)
             attn = kops.paged_attention(
-                q, new_cache, block_table, pos, seq_lengths,
+                q, new_cache, block_table, pos, seq_lengths, layer,
+                kv_heads=kv,
                 rope_theta=spec.rope_theta if self.kv_bits is not None
                 else None,
                 kv_bits=self.kv_bits,
                 kv_group=self.kv_group if self.kv_bits is not None else None)
             attn = attn.reshape(b, s, h_ * dh).astype(x.dtype)
         else:
-            new_cache = self._cache_write(cache, k, v, index)
-            k_all, v_all = self._cache_read(new_cache)
+            # a dense cache is a pool of one page per batch row
+            rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+            new_cache = self._paged_cache_write(cache, k, v, layer, pos,
+                                                rows)
+            k_all, v_all = self._cache_read(
+                jax.tree.map(lambda a: a[layer], new_cache))
             s_k = k_all.shape[1]
             if self.kv_bits is not None:
                 # integer cache holds pre-RoPE K: rotate after dequant
@@ -338,14 +317,19 @@ class QuantizedDenseLM:
         x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
         x = shard_act(x, ("batch", "seq", "embed"))
 
+        # the cache rides in the carry, written in place at [layer, page,
+        # slot] and read by the kernel through the layer index: scanning
+        # it as xs/ys would slice each layer out and stack it back
         def body(carry, inp):
-            blk, c = inp
-            x2, nc, stats = self._block(carry, blk, c, index, block_table,
-                                        seq_lengths, probe)
-            return x2, ((nc, stats) if probe else nc)
+            x, c = carry
+            blk, layer = inp
+            x, c, stats = self._block(x, blk, c, layer, index, block_table,
+                                      seq_lengths, probe)
+            return (x, c), stats
 
-        x, ys = jax.lax.scan(body, x, (params["layers"], cache))
-        new_cache, stats = ys if probe else (ys, None)
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        (x, new_cache), stats = jax.lax.scan(body, (x, cache),
+                                             (params["layers"], layers))
         x = L.apply_norm(x, params["final_norm"], cfg.norm)
         logits = x @ params["lm_head"].astype(x.dtype)
         if probe:

@@ -171,12 +171,13 @@ def test_walked_pages_accounting(stack):
 
 def test_integer_kv_pages_round_trip(stack):
     """Integer KV pages carry codes + scale/zero: after a run the pool
-    leaves keep the int8 code dtype and the engine still frees cleanly."""
+    leaves (K|V code rows, and their scale/zero rows) keep their dtypes
+    and the engine still frees cleanly."""
     adapter = _adapter(stack, "int_kv4")
     eng, done = _engine_run(adapter, PROMPTS[:2])
-    assert eng.kv.pool["k"].dtype == jnp.int8
-    assert set(eng.kv.pool) == {"k", "v", "k_scale", "v_scale",
-                                "k_zero", "v_zero"}
+    assert eng.kv.pool["kv_rows"].dtype == jnp.int8
+    assert eng.kv.pool["kv_scale_zero"].dtype == jnp.float32
+    assert set(eng.kv.pool) == {"kv_rows", "kv_scale_zero"}
     assert eng.kv.allocator.n_free == eng.kv.allocator.capacity
 
 

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops as kops
+from repro.kernels.paged_attention import AUX, pack_pages
 from repro.models import layers as L
 
 B, S_CHUNK, KH, G, DH = 3, 4, 2, 2, 32
@@ -29,6 +30,9 @@ H = KH * G
 
 
 def _make_pool(rng, fmt, n_pages, t, kh=KH):
+    """Logical pages {"k", "v" [n_pages, t, kh, DH]} (+ the four
+    scale/zero arrays [n_pages, t, kh, 1] of the integer formats) — what
+    the oracle reads; `_pool` packs them into the kernel's page format."""
     shape = (n_pages, t, kh, DH)
     if fmt == "float":
         return {"k": jnp.asarray(rng.standard_normal(shape), jnp.float32),
@@ -48,6 +52,23 @@ def _make_pool(rng, fmt, n_pages, t, kh=KH):
             "k_scale": aux(0.02, 0.2), "v_scale": aux(0.02, 0.2),
             "k_zero": jnp.round(aux(-12.0, 2.0)),
             "v_zero": jnp.round(aux(-12.0, 2.0))}
+
+
+def _pool(*layers):
+    """Logical pages of each layer → one pool in the page format
+    ([n_layers, n_pages, ...] leaves), through `pack_pages`."""
+    def stack(name):
+        return jnp.stack([kv[name] for kv in layers])
+    aux = None
+    if "k_scale" in layers[0]:
+        aux = tuple(stack(name)[..., 0] for name in AUX)
+    return pack_pages(stack("k"), stack("v"), aux)
+
+
+def _attend(q, kv, *args, **kw):
+    """`ops.paged_attention` over the logical pages `kv`, packed."""
+    return kops.paged_attention(q, _pool(kv), *args,
+                                kv_heads=kv["k"].shape[2], **kw)
 
 
 def _dequant(codes, scale, zero, bits):
@@ -114,9 +135,8 @@ def test_kernel_matches_gather_dense_oracle(page_size, fmt, kv_bits, s):
     kv = _make_pool(rng, fmt, n_pages, page_size)
     q = jnp.asarray(rng.standard_normal((B, s, H, DH)), jnp.float32)
 
-    got = kops.paged_attention(q, kv, bt, qpos, rope_theta=500000.0,
-                               kv_bits=kv_bits,
-                               kv_group=DH if kv_bits else None)
+    got = _attend(q, kv, bt, qpos, rope_theta=500000.0, kv_bits=kv_bits,
+                  kv_group=DH if kv_bits else None)
     want = _oracle(q, kv, bt, qpos, kv_bits=kv_bits, rope_theta=500000.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
@@ -130,9 +150,8 @@ def test_scratch_padded_columns_are_exact_noops():
     _, n_pages, bt, qpos = _ragged_setup(rng, 8, s=1)
     kv = _make_pool(rng, "float", n_pages, 8)
     q = jnp.asarray(rng.standard_normal((B, 1, H, DH)), jnp.float32)
-    narrow = kops.paged_attention(q, kv, bt, qpos)
-    wide = kops.paged_attention(
-        q, kv, jnp.pad(bt, ((0, 0), (0, 5))), qpos)
+    narrow = _attend(q, kv, bt, qpos)
+    wide = _attend(q, kv, jnp.pad(bt, ((0, 0), (0, 5))), qpos)
     np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))
 
 
@@ -159,10 +178,9 @@ def test_widening_is_exact_across_split_boundaries():
     qpos = jnp.asarray([[n - 1] for n in lengths], jnp.int32)
     kv = _make_pool(rng, "float", n_pages, t)
     q = jnp.asarray(rng.standard_normal((B, 1, H, DH)), jnp.float32)
-    base = kops.paged_attention(q, kv, bt, qpos)
+    base = _attend(q, kv, bt, qpos)
     for extra in (2, 10):                          # 8 and 16 columns
-        wide = kops.paged_attention(
-            q, kv, jnp.pad(bt, ((0, 0), (0, extra))), qpos)
+        wide = _attend(q, kv, jnp.pad(bt, ((0, 0), (0, extra))), qpos)
         np.testing.assert_array_equal(np.asarray(base), np.asarray(wide))
 
 
@@ -184,7 +202,7 @@ def test_gqa_tiling_sweep_matches_oracle(fmt, kv_bits, g, q_block,
     kv = _make_pool(rng, fmt, n_pages, page_size)
     q = jnp.asarray(rng.standard_normal((B, s, KH * g, DH)), jnp.float32)
 
-    got = kops.paged_attention(
+    got = _attend(
         q, kv, bt, qpos, jnp.asarray(lengths, jnp.int32),
         rope_theta=500000.0, kv_bits=kv_bits,
         kv_group=DH if kv_bits else None,
@@ -211,7 +229,7 @@ def test_split_config_dispatch_matches_reference_bitwise(fmt, kv_bits):
     outs = {}
     for enabled in (True, False):
         with kops.use_kernels(enabled):
-            outs[enabled] = np.asarray(kops.paged_attention(
+            outs[enabled] = np.asarray(_attend(
                 q, kv, bt, qpos, lens, rope_theta=500000.0,
                 kv_bits=kv_bits, kv_group=DH if kv_bits else None,
                 q_block=2, kv_splits=2, head_block=2))
@@ -231,9 +249,9 @@ def test_ragged_early_exit_is_exact(q_block, kv_splits, head_block):
     kv = _make_pool(rng, "float", n_pages, 8)
     q = jnp.asarray(rng.standard_normal((B, 1, H, DH)), jnp.float32)
     kw = dict(q_block=q_block, kv_splits=kv_splits, head_block=head_block)
-    trimmed = kops.paged_attention(
+    trimmed = _attend(
         q, kv, bt, qpos, jnp.asarray(lengths, jnp.int32), **kw)
-    full = kops.paged_attention(
+    full = _attend(
         q, kv, bt, qpos, jnp.full((B,), bt.shape[1] * 8, jnp.int32), **kw)
     np.testing.assert_array_equal(np.asarray(trimmed), np.asarray(full))
 
@@ -246,10 +264,9 @@ def test_zero_length_rows_skip_the_whole_walk():
     lengths, n_pages, bt, qpos = _ragged_setup(rng, 8, s=1)
     kv = _make_pool(rng, "float", n_pages, 8)
     q = jnp.asarray(rng.standard_normal((B, 1, H, DH)), jnp.float32)
-    base = kops.paged_attention(q, kv, bt, qpos,
-                                jnp.asarray(lengths, jnp.int32))
+    base = _attend(q, kv, bt, qpos, jnp.asarray(lengths, jnp.int32))
     lens0 = jnp.asarray([lengths[0], 0, lengths[2]], jnp.int32)
-    out = kops.paged_attention(q, kv, bt, qpos, lens0)
+    out = _attend(q, kv, bt, qpos, lens0)
     assert np.all(np.asarray(out[1]) == 0.0)
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(base[0]))
     np.testing.assert_array_equal(np.asarray(out[2]), np.asarray(base[2]))
@@ -264,7 +281,31 @@ def test_single_page_walk_tracks_plain_softmax_tightly():
     bt = jnp.asarray([[1], [2], [3]], jnp.int32)
     qpos = jnp.asarray([[15], [9], [4]], jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, 1, H, DH)), jnp.float32)
-    got = kops.paged_attention(q, kv, bt, qpos)
+    got = _attend(q, kv, bt, qpos)
     want = _oracle(q, kv, bt, qpos, kv_bits=None, rope_theta=None)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("fmt,kv_bits", [("float", None), ("int8", 8)])
+def test_layer_index_selects_the_layer(fmt, kv_bits):
+    """Two layers hold different pages under the same page ids: the walk
+    of layer i reads layer i's pages alone, on both dispatch paths."""
+    rng = np.random.default_rng(zlib.crc32(f"layers-{fmt}".encode()))
+    lengths, n_pages, bt, qpos = _ragged_setup(rng, 8, s=1)
+    layers = [_make_pool(rng, fmt, n_pages, 8) for _ in range(2)]
+    pool = _pool(*layers)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, DH)), jnp.float32)
+    kw = dict(kv_heads=KH, rope_theta=500000.0, kv_bits=kv_bits,
+              kv_group=DH if kv_bits else None)
+    outs = []
+    for i, kv in enumerate(layers):
+        want = _oracle(q, kv, bt, qpos, kv_bits=kv_bits, rope_theta=500000.0)
+        for enabled in (True, False):
+            with kops.use_kernels(enabled):
+                got = kops.paged_attention(q, pool, bt, qpos, None,
+                                           jnp.asarray(i, jnp.int32), **kw)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=3e-5, rtol=3e-5)
+        outs.append(np.asarray(got))
+    assert not np.allclose(outs[0], outs[1])

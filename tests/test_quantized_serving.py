@@ -102,12 +102,14 @@ def test_int4_kv_cache_quantization_error_small(setup):
     cfg, model, params, res = setup
     qlm = QuantizedDenseLM(cfg, block_size=16, kv_bits=4)
     cache = qlm.init_cache(2, 16)
-    one = jax.tree.map(lambda a: a[0], cache)
     k = jax.random.normal(jax.random.PRNGKey(2),
                           (2, 1, cfg.n_kv_heads, cfg.head_dim))
     v = jax.random.normal(jax.random.PRNGKey(3), k.shape)
-    new = qlm._cache_write(one, k, v, jnp.asarray(3))
-    kr, vr = qlm._cache_read(new)
+    # the dense cache is a pool of one page per batch row: position 3 of
+    # each row, layer 0
+    new = qlm._paged_cache_write(cache, k, v, 0, jnp.full((2, 1), 3),
+                                 jnp.arange(2)[:, None])
+    kr, vr = qlm._cache_read(jax.tree.map(lambda a: a[0], new))
     # int4 per-(position, head) scale: error ≤ scale/2 = absmax/14
     tol_k = float(jnp.max(jnp.abs(k))) / 14 + 1e-6
     tol_v = float(jnp.max(jnp.abs(v))) / 14 + 1e-6

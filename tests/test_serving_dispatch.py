@@ -14,6 +14,7 @@ import pytest
 
 from repro.configs.registry import get_config
 from repro.kernels import ops as kops
+from repro.kernels.paged_attention import pack_pages, unpack_pages
 from repro.models.transformer import build_model
 from repro.serve.quantized import QuantizedDenseLM, pack_dense_params
 
@@ -117,36 +118,30 @@ def test_paged_attention_dispatch_matches_reference_bitwise(kv_bits):
                      .reshape(b, n_cols), jnp.int32)
     qpos = jnp.asarray([[9 + j for j in range(s)],
                         [14 + j for j in range(s)]], jnp.int32)
-    shape = (n_pages, t, kh, dh)
+    shape = (1, n_pages, t, kh, dh)
     if kv_bits is None:
-        kv = {"k": jnp.asarray(rng.standard_normal(shape), jnp.float32),
-              "v": jnp.asarray(rng.standard_normal(shape), jnp.float32)}
+        kv = pack_pages(jnp.asarray(rng.standard_normal(shape), jnp.float32),
+                        jnp.asarray(rng.standard_normal(shape), jnp.float32))
     else:
         off, levels = 2 ** (kv_bits - 1), 2 ** kv_bits - 1
-        kv = {
-            "k": jnp.asarray(rng.integers(0, levels + 1, shape) - off,
-                             jnp.int8),
-            "v": jnp.asarray(rng.integers(0, levels + 1, shape) - off,
-                             jnp.int8),
-            "k_scale": jnp.asarray(rng.uniform(0.02, 0.2,
-                                               (n_pages, t, kh, 1)),
-                                   jnp.float32),
-            "v_scale": jnp.asarray(rng.uniform(0.02, 0.2,
-                                               (n_pages, t, kh, 1)),
-                                   jnp.float32),
-            "k_zero": jnp.asarray(
-                np.round(rng.uniform(-12, 2, (n_pages, t, kh, 1))),
-                jnp.float32),
-            "v_zero": jnp.asarray(
-                np.round(rng.uniform(-12, 2, (n_pages, t, kh, 1))),
-                jnp.float32),
-        }
+
+        def codes():
+            return jnp.asarray(rng.integers(0, levels + 1, shape) - off,
+                               jnp.int8)
+
+        def aux(lo, hi, rounded=False):
+            a = rng.uniform(lo, hi, shape[:-1])
+            return jnp.asarray(np.round(a) if rounded else a, jnp.float32)
+
+        kv = pack_pages(codes(), codes(),
+                        (aux(0.02, 0.2), aux(-12, 2, True), aux(0.02, 0.2),
+                         aux(-12, 2, True)))
     outs = {}
     for enabled in (True, False):
         with kops.use_kernels(enabled):
             outs[enabled] = np.asarray(kops.paged_attention(
-                q, kv, bt, qpos, rope_theta=500000.0, kv_bits=kv_bits,
-                kv_group=dh if kv_bits else None))
+                q, kv, bt, qpos, kv_heads=kh, rope_theta=500000.0,
+                kv_bits=kv_bits, kv_group=dh if kv_bits else None))
     np.testing.assert_array_equal(outs[True], outs[False])
 
 

@@ -28,6 +28,8 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
+from repro.kernels.paged_attention import pack_pages, unpack_pages
+from repro.models import layers as L
 from repro.models.transformer import build_model
 from repro.serve.engine import (EngineRequest, FaultPlan, HostSwapPool,
                                 SamplingParams, ServeEngine, as_servable)
@@ -108,6 +110,99 @@ def test_swap_roundtrip_bit_identical():
     assert cache.swap_in(0) == (0, 0)
     cache.release(0)
     assert cache.allocator.in_use == 0
+
+
+def test_page_format_round_trips_through_pool_machinery():
+    """Rows written into int8 pages of the tile-dense format (codes +
+    scale/zero rows, two layers) survive scrub → copy-on-write → swap out
+    to the host tier → swap back in bit for bit, and every page a step
+    does not name keeps its bytes."""
+    nl, n_pages, t, kh, dh = 2, 8, 4, 2, 64
+    rng = np.random.default_rng(5)
+    lead = (nl, n_pages, t, kh)
+    codes = jnp.zeros((*lead, dh), jnp.int8)
+    ones, zeros = jnp.ones(lead, jnp.float32), jnp.zeros(lead, jnp.float32)
+    cache = PagedKVCache(pack_pages(codes, codes, (ones, zeros, ones, zeros)),
+                         n_pages, t)
+    for rid in (0, 1):
+        cache.open(rid)
+        cache.ensure(rid, t)
+        bt = jnp.asarray([cache.tables[rid]], jnp.int32)
+        pos = jnp.arange(t)[None]
+        for layer in range(nl):
+            c = rng.integers(-128, 128, (2, 1, t, 1, kh, dh))
+            a = rng.uniform(-2, 2, (4, 1, t, 1, kh))
+            new = pack_pages(jnp.asarray(c[0], jnp.int8),
+                             jnp.asarray(c[1], jnp.int8),
+                             tuple(jnp.asarray(x, jnp.float32) for x in a))
+            cache.pool = L.paged_write_rows(cache.pool, new,
+                                            bt + layer * n_pages, pos)
+    page, other = cache.tables[0][0], cache.tables[1][0]
+
+    def snap():
+        return {k: np.asarray(v) for k, v in cache.pool.items()}
+
+    def check(before, moved):
+        """`moved` maps a page to what it must hold now (an array per
+        leaf); every other page must equal `before`."""
+        after = snap()
+        for k in after:
+            rest = [q for q in range(n_pages) if q not in moved]
+            np.testing.assert_array_equal(after[k][:, rest],
+                                          before[k][:, rest])
+            for q, want in moved.items():
+                np.testing.assert_array_equal(after[k][:, q], want[k])
+
+    written = snap()
+    assert all(np.any(written[k][:, page] != 0) for k in written)
+    cache.release(1)                                  # scrubs `other`
+    check(written, {other: {k: np.zeros_like(v[:, other])
+                            for k, v in written.items()}})
+    scrubbed = snap()
+    dst = cache.allocator.alloc(1)[0]
+    cache.cow_copy(page, dst)
+    check(scrubbed, {dst: {k: v[:, page] for k, v in written.items()}})
+    copied = snap()
+    cache.attach_host_pool(1)
+    assert cache.swap_out(0)[0] == 1 and cache.residency(0) == ["host"]
+    assert cache.swap_in(0)[0] == 1
+    back = cache.tables[0][0]
+    moved = {back: {k: v[:, page] for k, v in written.items()}}
+    if back != page:             # the page left behind was scrubbed
+        moved[page] = {k: np.zeros_like(v[:, page])
+                       for k, v in written.items()}
+    check(copied, moved)
+
+
+def test_oracle_gather_and_scatter_follow_the_page_format():
+    """The test oracles read and write pools in the page format: a
+    gathered slab holds each sequence's logical rows in table order, and
+    `scatter_decode_rows` puts a slab row back at (page, offset)."""
+    from repro.serve.engine import pages as PG
+    nl, n_pages, t, kh, dh = 2, 6, 4, 2, 64
+    rng = np.random.default_rng(9)
+    lead = (nl, n_pages, t, kh)
+    k, v = (jnp.asarray(rng.integers(-128, 128, (*lead, dh)), jnp.int8)
+            for _ in range(2))
+    aux = tuple(jnp.asarray(rng.uniform(-1, 1, lead), jnp.float32)
+                for _ in range(4))
+    pool = pack_pages(k, v, aux)
+    bt = jnp.asarray([[3, 1], [5, 2]], jnp.int32)
+    sk, sv, saux = unpack_pages(PG.gather_pages(pool, bt, kv_heads=kh), kh)
+    for got, want in zip((sk, sv, *saux), (k, v, *aux)):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want[:, bt]).reshape(got.shape))
+    slab = PG.gather_pages(pool, bt, kv_heads=kh)
+    # slot 0's position 5 (page 1, row 1) and slot 1's position 2 (page
+    # 5, row 2) go to page 4, rows 0 and 3
+    out = PG.scatter_decode_rows(pool, slab, jnp.asarray([5, 2]),
+                                 jnp.asarray([4, 4]), jnp.asarray([0, 3]),
+                                 kv_heads=kh)
+    ok, ov, oaux = unpack_pages(out, kh)
+    for got, want in zip((ok, ov, *oaux), (k, v, *aux)):
+        want = np.asarray(want).copy()
+        want[:, 4, 0], want[:, 4, 3] = want[:, 1, 1], want[:, 5, 2]
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_shared_pages_stay_device_resident():
