@@ -1,7 +1,8 @@
 """Whether what the timed path served is correct.
 
 After the window, samples drawn from the seed of the requests the engine
-served tokens to (finished or still decoding) are run through the float32 reference (`reference.py`) over
+served tokens to (finished or still decoding) are run through the
+configuration's model module's float32 reference (its `forward`) over
 prompt + served tokens: greedy requests (the longest always among them,
 then more until the configuration's `sample_tokens` served tokens are in)
 and, where the mix samples, as many tokens of sampled requests.
@@ -28,8 +29,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import reference
-from .weights import Dims, seed_key
+from .weights import seed_key
 
 
 def sample(rec, seed: int, want_tokens: int, greedy: bool):
@@ -115,7 +115,7 @@ def judge(readings: dict, corr: dict, sampled: bool):
     return checks, bool(ok)
 
 
-def readings(seed: int, dims: Dims, greedy, sampled, max_len: int, *,
+def readings(seed: int, model, dims, greedy, sampled, max_len: int, *,
              far_gap: float, control=False):
     """→ (the program's readings, the control's readings or None, the
     per-token gaps of both for a record), from one reference pass over the
@@ -126,8 +126,8 @@ def readings(seed: int, dims: Dims, greedy, sampled, max_len: int, *,
     key = seed_key(seed)
     streams, rows = streams_rows(picked)
     ng = sum(len(g) for _, g, _, _ in greedy)
-    ref = np.concatenate(reference.forward(key, dims, streams, rows,
-                                           max_len=max_len), axis=0)
+    ref = np.concatenate(model.forward(key, dims, streams, rows,
+                                       max_len=max_len), axis=0)
     served = np.concatenate([np.asarray(g, np.int64)
                              for _, g, _, _ in picked])
     temps = np.concatenate([np.full(len(g), t, np.float64)
@@ -139,8 +139,8 @@ def readings(seed: int, dims: Dims, greedy, sampled, max_len: int, *,
     detail = {"gaps": gaps(ref[:ng], served[:ng]).tolist()}
     ctrl = None
     if control:
-        lo = np.concatenate(reference.forward(key, dims, streams, rows,
-                                              mode="fp8", max_len=max_len))
+        lo = np.concatenate(model.forward(key, dims, streams, rows,
+                                          mode="fp8", max_len=max_len))
         first = lo.argmax(axis=-1)
         ctrl = _readings(ref[:ng], first[:ng], ref[ng:], first[ng:], temps,
                          top_ps, far_gap)

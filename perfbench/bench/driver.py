@@ -18,7 +18,6 @@ import numpy as np
 
 from . import spec, warmup
 from .traffic import Req
-from .weights import Dims
 
 sys.path.insert(0, str(spec.ROOT / "src"))
 
@@ -26,12 +25,8 @@ sys.path.insert(0, str(spec.ROOT / "src"))
 def _program():
     """The program's public serving API (imported late: a checkout without
     the program must fail here, not at import)."""
-    from repro.models.config import ArchConfig as AC
-    from repro.serve.engine import (EngineRequest, SamplingParams,
-                                    ServeEngine, as_servable)
-    from repro.serve.quantized import QuantizedDenseLM
-    return AC, EngineRequest, SamplingParams, ServeEngine, as_servable, \
-        QuantizedDenseLM
+    from repro.serve.engine import EngineRequest, SamplingParams, ServeEngine
+    return EngineRequest, SamplingParams, ServeEngine
 
 
 class CompileTally:
@@ -76,17 +71,13 @@ def use_checkout_cache(root=spec.ROOT) -> str:
     return enable_compile_cache()
 
 
-def build_engine(config: dict, dims: Dims, params, seed: int):
-    AC, _, _, ServeEngine, as_servable, QLM = _program()
+def build_engine(config: dict, servable, seed: int):
+    """The engine over a model module's `servable`, as the configuration's
+    `engine` section sets it."""
+    _, _, ServeEngine = _program()
     e = config["engine"]
-    cfg = AC(name=config["name"], family="dense", n_layers=dims.n_layers,
-             d_model=dims.d_model, vocab=dims.vocab, n_heads=dims.n_heads,
-             n_kv_heads=dims.n_kv_heads, head_dim=dims.head_dim,
-             rope_theta=dims.rope_theta, d_ff=dims.d_ff, act="silu",
-             norm="rmsnorm")
-    qlm = QLM(cfg, block_size=e["block_size"], kv_bits=e["kv_bits"])
     return ServeEngine(
-        as_servable(qlm, params), n_pages=e["n_pages"],
+        servable, n_pages=e["n_pages"],
         page_size=e["page_size"], max_seqs=e["max_seqs"],
         prefill_chunk=e["prefill_chunk"], token_budget=e["token_budget"],
         seed=int(seed) % (2 ** 31), admission=e["admission"],
@@ -94,7 +85,7 @@ def build_engine(config: dict, dims: Dims, params, seed: int):
 
 
 def _sampling(mix: dict, greedy: bool, max_new: int):
-    _, _, SamplingParams, _, _, _ = _program()
+    _, SamplingParams, _ = _program()
     s = mix["sampling"]
     return SamplingParams(temperature=0.0 if greedy else s["temperature"],
                           max_new=max_new, top_p=s["top_p"])
@@ -107,7 +98,7 @@ def run_warmup(engine, mix: dict, plan: warmup.Plan, vocab: int) -> int:
     """Serve the plan's requests one at a time (each alone in the engine,
     so its chunk is exactly `p` tokens), warm the page scrubs, then drop
     the cached warm-up prefixes. Returns the number of engine steps."""
-    _, EngineRequest, _, _, _, _ = _program()
+    EngineRequest, _, _ = _program()
     rng = np.random.default_rng(0)
     half = max(vocab // 2, 1)
     base = rng.integers(0, half, size=plan.base_len).tolist()
@@ -173,7 +164,7 @@ class Record:
     traced: tuple[float, float] | None   # profiler window, if any
     window_traces: int = 0               # jit traces inside the window
     engine: object = None
-    dims: Dims | None = None
+    dims: object = None                  # the model module's Dims
     trace: dict | None = None            # reduced profiler trace
     peaks: dict | None = None
 
@@ -243,7 +234,7 @@ def _step(engine, records_by_rid, rec_steps):
 
 
 def _submit(engine, rr: ReqRecord, records_by_rid):
-    _, EngineRequest, SamplingParams, _, _, _ = _program()
+    EngineRequest, SamplingParams, _ = _program()
     q = rr.req
     h = EngineRequest(rid=q.rid, prompt=q.prompt, sampling=SamplingParams(
         temperature=q.temperature, max_new=q.max_new, top_p=q.top_p))

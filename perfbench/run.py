@@ -4,8 +4,9 @@
         --seconds 30 --trace 0
 
 Everything a cell is made of is found by name: the cell in
-`BENCHMARK.json`, its configuration in `perfbench/configs/`, its traffic
-mix in `perfbench/traffic/`, the reader of each per-layer metric in
+`BENCHMARK.json`, its configuration in `perfbench/configs/`, the model
+module of its `model_type` in `perfbench/models/`, its traffic mix in
+`perfbench/traffic/`, the reader of each per-layer metric in
 `perfbench/readers/`. One run: make the weights on the device from the
 seed, build the engine, warm every shape the traffic reaches, offer the
 traffic and measure for `--seconds` (with `--trace 1`, under the profiler
@@ -72,20 +73,21 @@ def main(argv=None, *, require_tpu: bool = True, root: Path | None = None,
     sys.path.insert(0, str(root / "src"))
     from bench import check, driver, metrics, tracefile, traffic, warmup
     driver.use_checkout_cache(root)
-    from bench import weights as W
     from bench import work
 
     cfg, mix = cell.config, cell.traffic
-    dims = W.dims_of(cfg)
+    model = spec.load_model(cfg, root)
+    dims = model.dims_of(cfg)
     eng = cfg["engine"]
     dev = devs[0]
     pk = work.peaks(dev.device_kind) if require_tpu else \
         json.loads(work.PEAKS_FILE.read_text())["TPU v5 lite"]
 
     tally = driver.CompileTally()
-    params = W.make_params(args.seed, dims)
+    params = model.make_params(args.seed, dims)
     jax.block_until_ready(params)
-    engine = driver.build_engine(cfg, dims, params, args.seed)
+    engine = driver.build_engine(cfg, model.servable(cfg, dims, params),
+                                 args.seed)
     del params
     p_min, p_max, o_max = traffic.max_lengths(mix)
     plan = warmup.plan(p_min, p_max, o_max, chunk=eng["prefill_chunk"],
@@ -124,15 +126,15 @@ def main(argv=None, *, require_tpu: bool = True, root: Path | None = None,
         rec.trace = red
         lo, hi = rec.traced or rec.window
         rec.traced_steps = rec.steps_in(lo, hi)
-        rec.traced_work = work.window_work(rec.traced_steps, dims, pk,
-                                           eng["prefill_chunk"])
+        rec.traced_work = work.window_work(model, rec.traced_steps, dims,
+                                           pk, eng["prefill_chunk"])
         if red is not None:
             device["busy_s"] = red["busy_s"]
             device["window_s"] = hi - lo
             breakdown = {"device_ops": red["ops"],
                          "idle_gaps": red["idle_gaps"]}
         for m in cell.per_layer:
-            v = spec.load_reader(m["name"]).read(rec)
+            v = spec.load_reader(m["name"], root).read(rec)
             if v is not None:
                 out_metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:
@@ -157,7 +159,7 @@ def main(argv=None, *, require_tpu: bool = True, root: Path | None = None,
     gc.collect()
     t_ref = time.perf_counter()
     prog, ctrl, detail = check.readings(
-        args.seed, dims, picks[0], picks[1] if sampled else [],
+        args.seed, model, dims, picks[0], picks[1] if sampled else [],
         p_max + o_max, far_gap=corr["far_gap"], control=bool(args.control))
     checks, ok = check.judge(prog, corr, sampled)
     _err(f"served tokens against the reference: {prog}")

@@ -10,23 +10,20 @@ import numpy as np
 import pytest
 
 import tiny
-from bench import reference
-from bench import weights as W
+from bench import spec
+from bench.weights import seed_key
+
+granite = spec.load_model(tiny.CONFIG)
 
 
 def _served_logits(dtype):
-    from repro.models.config import ArchConfig
     from repro.serve.engine import (EngineRequest, SamplingParams,
                                     ServeEngine, as_servable)
     from repro.serve.quantized import QuantizedDenseLM
-    dims = W.dims_of(tiny.CONFIG)
-    params = W.make_params(5, dims)
-    cfg = ArchConfig(name="t", family="dense", n_layers=dims.n_layers,
-                     d_model=dims.d_model, vocab=dims.vocab,
-                     n_heads=dims.n_heads, n_kv_heads=dims.n_kv_heads,
-                     head_dim=dims.head_dim, rope_theta=dims.rope_theta,
-                     d_ff=dims.d_ff)
-    cfg = dataclasses.replace(cfg, compute_dtype=dtype, param_dtype=dtype)
+    dims = granite.dims_of(tiny.CONFIG)
+    params = granite.make_params(5, dims)
+    cfg = dataclasses.replace(granite.arch(tiny.CONFIG, dims),
+                              compute_dtype=dtype, param_dtype=dtype)
     if dtype == "float32":
         params = jax.tree.map(lambda a: a.astype(jnp.float32)
                               if a.dtype == jnp.bfloat16 else a, params)
@@ -38,8 +35,8 @@ def _served_logits(dtype):
     eng.submit(EngineRequest(rid=1, prompt=prompt,
                              sampling=SamplingParams(max_new=10)))
     r = eng.run()[0]
-    ref = reference.forward(W.seed_key(5), dims, [prompt + r.generated],
-                            [list(range(39, 49))])[0]
+    ref = granite.forward(seed_key(5), dims, [prompt + r.generated],
+                          [list(range(39, 49))])[0]
     return np.stack(r.step_logits), ref
 
 

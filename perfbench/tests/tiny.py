@@ -13,10 +13,11 @@ from bench import spec  # noqa: E402
 CONFIG = {
     "name": "tiny",
     "source": "test",
-    "model": {"hidden_size": 128, "intermediate_size": 256,
-              "num_hidden_layers": 2, "num_attention_heads": 4,
-              "num_key_value_heads": 2, "head_dim": 32, "vocab_size": 512,
-              "rope_theta": 10000.0, "rms_norm_eps": 1e-06},
+    "model": {"model_type": "granite", "hidden_size": 128,
+              "intermediate_size": 256, "num_hidden_layers": 2,
+              "num_attention_heads": 4, "num_key_value_heads": 2,
+              "head_dim": 32, "vocab_size": 512, "rope_theta": 10000.0,
+              "rms_norm_eps": 1e-06},
     "deployment": {"layers_published": 2, "chips": 1},
     "weights": {"logit_std": 3.0},
     "engine": {"kv_bits": 8, "block_size": 16, "page_size": 16,

@@ -23,54 +23,48 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from bench import spec, weights as W  # noqa: E402
+from bench import spec  # noqa: E402
 
 
 def main(path, chunk=None):
     from repro.kernels import ops as kops
-    from repro.models.config import ArchConfig
-    from repro.serve.quantized import QuantizedDenseLM
     jax.config.update("jax_enable_compilation_cache", False)
     kops._on_tpu = lambda: True
     config = spec.load_json(Path(path))
-    dims = W.dims_of(config)
+    model = spec.load_model(config)
+    dims = model.dims_of(config)
     e = config["engine"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
-    cfg = ArchConfig(name=config["name"], family="dense",
-                     n_layers=dims.n_layers, d_model=dims.d_model,
-                     vocab=dims.vocab, n_heads=dims.n_heads,
-                     n_kv_heads=dims.n_kv_heads, head_dim=dims.head_dim,
-                     rope_theta=dims.rope_theta, d_ff=dims.d_ff)
-    qlm = QuantizedDenseLM(cfg, block_size=e["block_size"],
-                           kv_bits=e["kv_bits"])
 
     def sds(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one), tree)
 
-    params = sds(jax.eval_shape(lambda: W.make_params(0, dims)))
-    n_pages = e["n_pages"]
-    cache = sds(jax.eval_shape(lambda: qlm.init_cache(n_pages,
-                                                      e["page_size"])))
-    i32 = jnp.int32
+    params = sds(jax.eval_shape(lambda: model.make_params(0, dims)))
+    sv = model.servable(config, dims, params)
     b = e["max_seqs"]
+    state = sds(jax.eval_shape(lambda: sv.init_state(
+        e["n_pages"], e["page_size"], b)))
+    i32 = jnp.int32
     for name, (tok, idx, bt, lens) in {
         "prefill": ((1, chunk or e["prefill_chunk"]), (), (1, 64), (1,)),
         "decode": ((b, 1), (b,), (b, 64), (b,)),
     }.items():
-        args = (params, jax.ShapeDtypeStruct(tok, i32, sharding=one), cache,
+        args = (params, jax.ShapeDtypeStruct(tok, i32, sharding=one), state,
                 jax.ShapeDtypeStruct(idx, i32, sharding=one),
                 jax.ShapeDtypeStruct(bt, i32, sharding=one),
                 jax.ShapeDtypeStruct(lens, i32, sharding=one))
         t = time.time()
-        comp = jax.jit(qlm._forward, donate_argnums=(2,)).lower(
+        comp = jax.jit(sv.forward_chunk, donate_argnums=(2,)).lower(
             *args).compile()
         ma = comp.memory_analysis()
-        print(f"{name}: compiled in {time.time() - t:.1f}s; "
-              f"args {ma.argument_size_in_bytes / 2**30:.2f} GiB, temp "
-              f"{ma.temp_size_in_bytes / 2**30:.2f} GiB, custom calls "
+        print(f"{name}: compiled in {time.time() - t:.1f}s; args "
+              f"{ma.argument_size_in_bytes} B "
+              f"({ma.argument_size_in_bytes / 2**30:.2f} GiB), temp "
+              f"{ma.temp_size_in_bytes} B "
+              f"({ma.temp_size_in_bytes / 2**30:.2f} GiB), custom calls "
               f"{comp.as_text().count('tpu_custom_call')}", flush=True)
 
 

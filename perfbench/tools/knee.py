@@ -17,7 +17,6 @@ HERE = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
 
 from bench import driver, metrics, spec, traffic, warmup  # noqa: E402
-from bench import weights as W  # noqa: E402
 
 
 def waiting(rec, t):
@@ -35,10 +34,12 @@ def main():
     cell = spec.load_cell(args.workload)
     driver.use_checkout_cache()
     cfg, mix = cell.config, cell.traffic
-    dims = W.dims_of(cfg)
+    model = spec.load_model(cfg)
+    dims = model.dims_of(cfg)
     eng = cfg["engine"]
     t0 = time.perf_counter()
-    engine = driver.build_engine(cfg, dims, W.make_params(args.seed, dims),
+    params = model.make_params(args.seed, dims)
+    engine = driver.build_engine(cfg, model.servable(cfg, dims, params),
                                  args.seed)
     p_min, p_max, o_max = traffic.max_lengths(mix)
     plan = warmup.plan(p_min, p_max, o_max, chunk=eng["prefill_chunk"],
